@@ -87,8 +87,8 @@ def run(quick: bool = False):
         n_pages = 1 + B * P
         ks = jax.random.split(jax.random.fold_in(key, P), 3)
         q = jax.random.normal(ks[0], (B, 1, H, hd))
-        kp = jax.random.normal(ks[1], (n_pages, psz, KV, hd))
-        vp = jax.random.normal(ks[2], (n_pages, psz, KV, hd))
+        kp = jax.random.normal(ks[1], (n_pages, KV, psz, hd))
+        vp = jax.random.normal(ks[2], (n_pages, KV, psz, hd))
         bt = jnp.asarray(np.random.default_rng(P).permutation(
             np.arange(1, n_pages)).reshape(B, P), jnp.int32)
         last = jnp.asarray(np.random.default_rng(P + 1).integers(

@@ -393,8 +393,8 @@ def run(quick: bool = False):
     rng = np.random.default_rng(5)
     import jax.numpy as jnp
     qk = jax.random.normal(jax.random.PRNGKey(5), (B, 1, H, hd))
-    kpool = jax.random.normal(jax.random.PRNGKey(6), (kp, psz, KV, hd))
-    vpool = jax.random.normal(jax.random.PRNGKey(7), (kp, psz, KV, hd))
+    kpool = jax.random.normal(jax.random.PRNGKey(6), (kp, KV, psz, hd))
+    vpool = jax.random.normal(jax.random.PRNGKey(7), (kp, KV, psz, hd))
     bt = jnp.asarray(rng.permutation(np.arange(1, kp)).reshape(B, P),
                      jnp.int32)
     last = jnp.asarray(rng.integers(psz, P * psz, B), jnp.int32)
@@ -578,6 +578,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     import numpy as np
 
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models import params as Pm
     from repro.serving.sampling import SamplingParams
     from repro.serving.scheduler import (ContinuousBatcher, Request,
@@ -611,7 +612,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
         toks = sum(len(c.tokens) for c in done)
         return done, toks / wall, b.decode_dispatches - d0, ticks
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     out = {"slots": n_slots, "mesh": "2x2"}
     res = {}
     for name, layout, m in (("single", "dense", None),
@@ -640,6 +641,9 @@ _SHARDED_SCRIPT = textwrap.dedent("""
 def _sharded_row(quick: bool):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # this row counts on 8 virtual CPU devices; the parent may already hold
+    # the accelerator, so the child must not reach for it
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))

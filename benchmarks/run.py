@@ -64,6 +64,10 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     import importlib
 
     print("name,us_per_call,derived")

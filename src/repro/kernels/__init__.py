@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels: flash attention, paged attention, the chunked
+GLA scan and GreedyTL scoring.  Each package holds the kernel, a jitted
+model-layout wrapper (``ops.py``) and a pure-jnp oracle (``ref.py``)."""
+import jax
+
+
+def on_tpu() -> bool:
+    """True when the default backend is a TPU: the ops wrappers lower their
+    kernels to Mosaic there and run the Pallas interpreter everywhere else
+    (the CPU test suite)."""
+    return jax.default_backend() == "tpu"

@@ -78,8 +78,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     jax.jit, static_argnames=("causal", "window", "chunk", "block_q",
                               "block_k", "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal=True, window=0, chunk=0,
-                         block_q=128, block_k=128, interpret=True):
+                         block_q=128, block_k=128, interpret: bool):
     """q: (B, H, S, hd); k/v: (B, H, S, hd) (GQA pre-expanded by ops.py).
+    interpret: run the Pallas interpreter instead of lowering to Mosaic
+    (CPU tests).
 
     Returns (B, H, S, hd)."""
     B, H, S, hd = q.shape

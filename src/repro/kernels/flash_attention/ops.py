@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit,
@@ -35,5 +32,5 @@ def flash_attention(q, k, v, *, causal=True, window=0, chunk=0,
         vh = jnp.repeat(vh, g, axis=1)
     out = flash_attention_bhsd(qh, kh, vh, causal=causal, window=window,
                                chunk=chunk, block_q=block_q, block_k=block_k,
-                               interpret=not _on_tpu())
+                               interpret=not on_tpu())
     return jnp.transpose(out, (0, 2, 1, 3))

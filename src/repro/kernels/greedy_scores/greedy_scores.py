@@ -51,8 +51,9 @@ def _gram_kernel(z1_ref, z2_ref, o_ref, acc_ref, *, n_m: int):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m",
                                              "interpret"))
-def gram(Z, *, block_n: int = 128, block_m: int = 128, interpret=True):
-    """G = Z^T Z.  Z: (m, n); returns (n, n) float32."""
+def gram(Z, *, block_n: int = 128, block_m: int = 128, interpret: bool):
+    """G = Z^T Z.  Z: (m, n); returns (n, n) float32.  interpret: run the
+    Pallas interpreter instead of lowering to Mosaic (CPU tests)."""
     m, n = Z.shape
     bn = min(block_n, n)
     bm = min(block_m, m)
@@ -78,46 +79,50 @@ def gram(Z, *, block_n: int = 128, block_m: int = 128, interpret=True):
 def _scores_kernel(corr_ref, diag_ref, sel_ref, scores_ref, best_ref,
                    *, lam: float, block_n: int):
     i = pl.program_id(0)
-    corr = corr_ref[...].astype(jnp.float32)
+    corr = corr_ref[...].astype(jnp.float32)       # (1, bn)
     diag = diag_ref[...].astype(jnp.float32)
     sel = sel_ref[...]
     s = (corr * corr) / (diag + lam)
     s = jnp.where(sel > 0, NEG_INF, s)
     scores_ref[...] = s
-    j = jnp.argmax(s)
-    best_ref[0, 0] = s[j]
-    best_ref[0, 1] = (i * block_n + j).astype(jnp.float32)
+    # blockwise (max, first argmax) from two lane reductions — the
+    # first index attaining the max, as jnp.argmax picks
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    best = jnp.max(s, axis=1, keepdims=True)       # (1, 1)
+    j = jnp.min(jnp.where(s == best, lane, block_n), axis=1, keepdims=True)
+    idx = (i * block_n + j).astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1)
+    best_ref[0] = jnp.where(col == 0, best, idx)
 
 
 @functools.partial(jax.jit, static_argnames=("lam", "block_n", "interpret"))
 def scores_argmax(corr, diag, selected_mask, lam: float,
-                  *, block_n: int = 256, interpret=True):
+                  *, block_n: int = 256, interpret: bool):
     """Returns (scores (n,), best_idx scalar int32).
 
     corr/diag: (n,) float; selected_mask: (n,) {0,1}.  The blockwise
     (max, argmax) pairs are reduced on the host side of the op (ops.py) —
-    a (n/block_n, 2) table, negligible traffic."""
+    a (n/block_n, 2) table, negligible traffic.  The vectors ride as
+    (1, n) rows and the table as (n/block_n, 1, 2), so every block's last
+    two dims are whole or (8, 128)-aligned.  interpret: run the Pallas
+    interpreter instead of lowering to Mosaic (CPU tests)."""
     n = corr.shape[0]
     bn = min(block_n, n)
     assert n % bn == 0, (n, bn)
     n_blocks = n // bn
+    row = pl.BlockSpec((1, bn), lambda i: (0, i))
     scores, best = pl.pallas_call(
         functools.partial(_scores_kernel, lam=lam, block_n=bn),
         grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        ],
+        in_specs=[row, row, row],
+        out_specs=[row, pl.BlockSpec((1, 1, 2), lambda i: (i, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, 2), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, 2), jnp.float32),
         ],
         interpret=interpret,
-    )(corr, diag, selected_mask.astype(jnp.float32))
+    )(corr.reshape(1, n), diag.reshape(1, n),
+      selected_mask.astype(jnp.float32).reshape(1, n))
+    best = best[:, 0]
     blk = jnp.argmax(best[:, 0])
-    return scores, best[blk, 1].astype(jnp.int32)
+    return scores[0], best[blk, 1].astype(jnp.int32)

@@ -7,11 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.greedy_scores import greedy_scores as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x, mult, axis, value=0.0):
@@ -31,7 +28,7 @@ def gram(Z, *, block_n: int = 128, block_m: int = 128):
     m, n = Z.shape
     Zp = _pad_to(_pad_to(Z, block_m, 0), block_n, 1)
     G = K.gram(Zp, block_n=block_n, block_m=block_m,
-               interpret=not _on_tpu())
+               interpret=not on_tpu())
     return G[:n, :n]
 
 
@@ -44,5 +41,5 @@ def scores_argmax(corr, diag, selected_mask, lam: float,
     dp = _pad_to(diag, block_n, 0, value=1.0)
     sp = _pad_to(selected_mask.astype(jnp.float32), block_n, 0, value=1.0)
     scores, idx = K.scores_argmax(cp, dp, sp, lam, block_n=block_n,
-                                  interpret=not _on_tpu())
+                                  interpret=not on_tpu())
     return scores[:n], idx

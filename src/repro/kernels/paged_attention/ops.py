@@ -23,14 +23,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.paged_attention.paged_attention import \
     paged_attention_grouped
 
 DEFAULT_TILE_K = 4  # pages per MXU tile (page grid steps between dots)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _validate(q, k_pool, v_pool, block_table, last_pos, q_positions):
@@ -40,9 +37,9 @@ def _validate(q, k_pool, v_pool, block_table, last_pos, q_positions):
     B, S, H, hd = q.shape
     if k_pool.shape != v_pool.shape or k_pool.ndim != 4:
         raise ValueError(
-            f"k_pool/v_pool must be matching (n_pages, page_size, KV, hd) "
+            f"k_pool/v_pool must be matching (n_pages, KV, page_size, hd) "
             f"pools; got {k_pool.shape} vs {v_pool.shape}")
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     if H % KV:
         raise ValueError(
             f"H={H} query heads must group onto KV={KV} pool heads (GQA)")
@@ -57,7 +54,7 @@ def _validate(q, k_pool, v_pool, block_table, last_pos, q_positions):
         raise ValueError(
             f"q_positions must be (B, S) int32; got "
             f"{q_positions.shape} {q_positions.dtype}")
-    T = block_table.shape[1] * k_pool.shape[1]
+    T = block_table.shape[1] * k_pool.shape[2]
     if not 1 <= S <= T:
         raise ValueError(
             f"S={S} query block must satisfy 1 <= S <= ring length {T} "
@@ -71,9 +68,9 @@ def _dispatch(q, k_new, v_new, k_pool, v_pool, block_table, last_pos,
     multiple of tile_k with the null page 0, dispatch."""
     _validate(q, k_pool, v_pool, block_table, last_pos, q_positions)
     B, S, H, hd = q.shape
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     g = H // KV
-    psz = k_pool.shape[1]
+    psz = k_pool.shape[2]
     P = block_table.shape[1]
     T = P * psz
 
@@ -93,7 +90,7 @@ def _dispatch(q, k_new, v_new, k_pool, v_pool, block_table, last_pos,
     res = paged_attention_grouped(
         qg, k_new, v_new, k_pool, v_pool, block_table, q_positions,
         last_pos, ring_len=T, window=window, tile_k=tk,
-        interpret=not _on_tpu())
+        interpret=not on_tpu())
     out, kp, vp = res if k_new is not None else (res, k_pool, v_pool)
     out = out.reshape(B, KV, S, g, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, S, H, hd)
@@ -108,7 +105,7 @@ def paged_attention(q, k_pool, v_pool, block_table, last_pos, *,
     slot, already RoPE'd; its K/V must already be scattered into the pool
     (use `paged_attention_update` to fuse that write in).
 
-    k_pool/v_pool: (n_pages, page_size, KV, hd) shared pools.
+    k_pool/v_pool: (n_pages, KV, page_size, hd) shared pools.
     block_table: (B, P) int32 page ids; last_pos: (B,) int32 absolute
     position of the newest token per slot.  q_positions: optional (B, S)
     int32 per-row query positions (defaults to last_pos - S + 1 .. last_pos,
@@ -132,10 +129,10 @@ def paged_attention_update(q, k_new, v_new, k_pool, v_pool, block_table,
     (out, k_pool, v_pool): out (B, S, H, hd) plus the updated pools
     (aliased in-place onto the inputs)."""
     B, S = q.shape[:2]
-    if k_new.shape != (B, S) + k_pool.shape[2:] or k_new.shape != v_new.shape:
+    want = (B, S, k_pool.shape[1], k_pool.shape[3])
+    if k_new.shape != want or k_new.shape != v_new.shape:
         raise ValueError(
-            f"k_new/v_new must be (B, S, KV, hd) = "
-            f"{(B, S) + k_pool.shape[2:]}; got {k_new.shape} / "
-            f"{v_new.shape}")
+            f"k_new/v_new must be (B, S, KV, hd) = {want}; got "
+            f"{k_new.shape} / {v_new.shape}")
     return _dispatch(q, k_new, v_new, k_pool, v_pool, block_table,
                      last_pos, window, tile_k, q_positions)

@@ -6,7 +6,10 @@ ring, which lives scattered across a shared page pool and is addressed
 through a per-slot block table riding in as a scalar-prefetch operand —
 each grid step's BlockSpec index_map picks the page tile to DMA straight
 out of the pool, so no (B, T, KV, hd) gather ever materializes in HBM
-(the XLA path in models/layers.py pays that copy every tick).
+(the XLA path in models/layers.py pays that copy every tick).  The pool
+is laid out (n_pages, KV, page_size, hd), so the tile of one (page, KV
+head) is a contiguous (page_size, hd) block: its last two dims are whole,
+as the TPU lowering requires of a block.
 
 Three rungs over the v1 decode-only kernel:
 
@@ -14,10 +17,11 @@ Three rungs over the v1 decode-only kernel:
   (B, KV, S, hd) k_new/v_new rows and writes them into their
   block-table-addressed page rows in the same grid pass that reads the
   page (`input_output_aliases` pins the pool outputs onto the pool
-  inputs, so the write is in-place).  The per-row select is a one-hot
-  (page_size, S) matmul — `W @ k_new` — not a gather, so it vectorizes
-  on the MXU.  This deletes the separate XLA pool scatter that v1 paid
-  as a second HBM traversal of the pool every tick.
+  inputs, so the write is in-place).  Each new row lands by an S-step
+  loop of row selects on the (page_size, hd) tile — exact for every pool
+  dtype, with no gather and no MXU round trip.  This deletes the
+  separate XLA pool scatter that v1 paid as a second HBM traversal of
+  the pool every tick.
 - MULTI-PAGE TILES.  The page grid dimension stays one page per step
   (pages are scattered in the pool, so one BlockSpec can only DMA one),
   but K/V tiles accumulate into a (tile_k * page_size, hd) VMEM scratch
@@ -29,7 +33,9 @@ Three rungs over the v1 decode-only kernel:
   heads per KV head, GQA); row r is query token r // g at position
   q_pos[b, r // g], masked causally per row — so chunked prefill,
   preemption resume-recompute, and speculative verify run through the
-  kernel instead of falling back to the XLA gather.
+  kernel instead of falling back to the XLA gather.  The row positions
+  ride in as an (S*g, 1) VMEM column: vector reads of the
+  scalar-prefetched operands do not lower to Mosaic.
 
 Masking: a ring entry is admitted only when the absolute position it
 holds (the largest value congruent to its ring index mod T that is
@@ -63,14 +69,14 @@ import jax.experimental.pallas.tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(bt_ref, qpos_ref, last_ref, *refs, scale: float, page_size: int,
-            n_steps: int, tile_k: int, window: int, S: int, g: int, T: int,
+def _kernel(bt_ref, last_ref, *refs, scale: float, page_size: int,
+            n_steps: int, tile_k: int, window: int, S: int, T: int,
             fuse: bool):
     if fuse:
-        (q_ref, kn_ref, vn_ref, k_ref, v_ref, o_ref, ko_ref, vo_ref,
-         acc_ref, m_ref, l_ref, kbuf_ref, vbuf_ref) = refs
+        (rpos_ref, q_ref, kn_ref, vn_ref, k_ref, v_ref, o_ref, ko_ref,
+         vo_ref, acc_ref, m_ref, l_ref, kbuf_ref, vbuf_ref) = refs
     else:
-        (q_ref, k_ref, v_ref, o_ref,
+        (rpos_ref, q_ref, k_ref, v_ref, o_ref,
          acc_ref, m_ref, l_ref, kbuf_ref, vbuf_ref) = refs
     b = pl.program_id(0)
     ip = pl.program_id(2)
@@ -83,35 +89,43 @@ def _kernel(bt_ref, qpos_ref, last_ref, *refs, scale: float, page_size: int,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     last = last_ref[b]
-    k_tile = k_ref[0, :, 0, :]                     # (psz, hd) pool dtype
-    v_tile = v_ref[0, :, 0, :]
+    k_tile = k_ref[0, 0]                           # (psz, hd) pool dtype
+    v_tile = v_ref[0, 0]
 
     if fuse:
         # scatter the S new rows into this page tile: ring slot
-        # (first + s) % T holds new token s, first = last - S + 1.  The
-        # row select is a one-hot (psz, S) matmul so it stays on the MXU;
-        # rows outside [first..last] (mod T) or past the real ring (the
-        # null-page padding) keep the pool's bytes.
-        first = last - (S - 1)
+        # (first + s) % T holds new token s, first = last - S + 1.  Row r
+        # of the page takes new token rel[r] = (r - first) mod T when
+        # rel < S; rows outside [first..last] (mod T) or past the real
+        # ring (the null-page padding) keep the pool's bytes.  The modulus
+        # is taken on the scalar unit; the vector side only selects.
+        first_mod = jnp.mod(last - (S - 1), T)
         rows = ip * psz + jax.lax.broadcasted_iota(jnp.int32, (psz, 1), 0)
-        rel = jnp.mod(rows - first, T)             # (psz, 1)
-        wm = (rel < S) & (rows < T)                # (psz, 1)
-        sel = rel == jax.lax.broadcasted_iota(jnp.int32, (psz, S), 1)
-        w = jnp.where(wm, sel, False).astype(jnp.float32)       # (psz, S)
-        kn = kn_ref[0, 0].astype(jnp.float32)      # (S, hd)
-        vn = vn_ref[0, 0].astype(jnp.float32)
-        # cast BEFORE the attention read: the pool may store narrower
-        # kv_cache_dtype and the XLA path round-trips through it too
-        k_tile = jnp.where(wm, (w @ kn).astype(k_tile.dtype), k_tile)
-        v_tile = jnp.where(wm, (w @ vn).astype(v_tile.dtype), v_tile)
-        ko_ref[0, :, 0, :] = k_tile
-        vo_ref[0, :, 0, :] = v_tile
+        rel = rows - first_mod + jnp.where(rows < first_mod, T, 0)
+        live = (rel < S) & (rows < T)              # (psz, 1)
+
+        def put(s, tiles):
+            kt, vt = tiles
+            hit = live & (rel == s)
+            # cast BEFORE the attention read: the pool may store narrower
+            # kv_cache_dtype and the XLA path round-trips through it too
+            kt = jnp.where(hit, kn_ref[0, 0, pl.ds(s, 1), :].astype(
+                kt.dtype), kt)
+            vt = jnp.where(hit, vn_ref[0, 0, pl.ds(s, 1), :].astype(
+                vt.dtype), vt)
+            return kt, vt
+
+        k_tile, v_tile = jax.lax.fori_loop(0, S, put, (k_tile, v_tile),
+                                           unroll=S <= 16)
+        ko_ref[0, 0] = k_tile
+        vo_ref[0, 0] = v_tile
 
     # accumulate this page into the multi-page tile buffer; the flash
     # update fires once per tile_k pages on the whole buffer
     j = jax.lax.rem(ip, tile_k)
-    kbuf_ref[pl.ds(j * psz, psz), :] = k_tile.astype(jnp.float32)
-    vbuf_ref[pl.ds(j * psz, psz), :] = v_tile.astype(jnp.float32)
+    off = pl.multiple_of(j * psz, psz)
+    kbuf_ref[pl.ds(off, psz), :] = k_tile.astype(jnp.float32)
+    vbuf_ref[pl.ds(off, psz), :] = v_tile.astype(jnp.float32)
 
     @pl.when(j == tile_k - 1)
     def _flash():
@@ -121,35 +135,38 @@ def _kernel(bt_ref, qpos_ref, last_ref, *refs, scale: float, page_size: int,
             q, kbuf_ref[...], (((1,), (1,)), ((), ()))) * scale  # (S*g, L)
 
         # absolute position held by each ring entry of the tile: the
-        # largest value congruent to its ring index (mod T) <= `last`
+        # largest value congruent to its ring index (mod T) <= `last`,
+        # i.e. last - ((last - ring) mod T) with the modulus on the scalar
+        # unit: ring entries past last % T belong to the previous lap
+        last_mod = jnp.mod(last, T)
         base = (ip - (tile_k - 1)) * psz
-        ring = base + jax.lax.broadcasted_iota(jnp.int32, (S * g, L), 1)
-        k_pos = last - jnp.mod(last - ring, T)
-        # row r of the query block is token r // g at position qpos[r//g]
-        # (broadcast+reshape, not jnp.repeat — repeat's general lowering
-        # emits cumsum/scatter ops the no-pool-scatter HLO oracle counts)
-        row_pos = jnp.broadcast_to(
-            qpos_ref[b, :][:, None], (S, g)).reshape(S * g)[:, None]
+        ring = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        k_pos = (last - last_mod) + ring - jnp.where(ring > last_mod, T, 0)
+        row_pos = rpos_ref[0]                      # (S*g, 1) query position
         mask = (k_pos >= 0) & (ring < T) & (k_pos <= row_pos)
         if window:
             mask &= k_pos > (row_pos - window)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]                        # (S*g,)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_ref[...]                        # (S*g, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
+        p = jnp.exp(s - m_cur)
         # fully-masked tiles (idle slot parked on the null page, padding
         # past the ring, tail tiles past `last`): stay at zero
         p = jnp.where(mask, p, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + p @ vbuf_ref[...]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # HIGHEST: at its default precision Mosaic rounds fp32 operands
+        # to bf16, so the chip would drop low bits of p that interpret
+        # mode keeps
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p, vbuf_ref[...], precision=jax.lax.Precision.HIGHEST)
         m_ref[...] = m_cur
 
     @pl.when(ip == n_steps - 1)
     def _out():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -157,43 +174,52 @@ def _kernel(bt_ref, qpos_ref, last_ref, *refs, scale: float, page_size: int,
 def paged_attention_grouped(q, k_new, v_new, k_pool, v_pool, block_table,
                             q_pos, last_pos, *, ring_len: int,
                             window: int = 0, tile_k: int = 1,
-                            interpret: bool = True):
+                            interpret: bool):
     """q: (B, KV, S*g, hd) — GQA-grouped S-token query blocks (ops.py maps
     the model layout).  k_new/v_new: (B, KV, S, hd) just-projected rows to
     scatter in-kernel, or both None for attention-only (pool already holds
-    them).  k_pool/v_pool: (n_pages, page_size, KV, hd).  block_table:
-    (B, P_pad) int32 page ids, P_pad a multiple of tile_k (ops.py pads
-    with the null page 0).  q_pos: (B, S) int32 per-row query positions.
-    last_pos: (B,) int32 newest WRITE position per slot (masking modulus
-    anchor — and the write window [last-S+1 .. last] when fusing).
-    ring_len: the real (unpadded) logical ring length P * page_size.
+    them).  k_pool/v_pool: (n_pages, KV, page_size, hd), so one grid step
+    streams one head's page as a contiguous (page_size, hd) block.
+    block_table: (B, P_pad) int32 page ids, P_pad a multiple of tile_k
+    (ops.py pads with the null page 0).  q_pos: (B, S) int32 per-row query
+    positions.  last_pos: (B,) int32 newest WRITE position per slot
+    (masking modulus anchor — and the write window [last-S+1 .. last]
+    when fusing).  ring_len: the real (unpadded) logical ring length
+    P * page_size.  interpret: run the Pallas interpreter instead of
+    lowering to Mosaic (CPU tests).
     Returns (out, k_pool, v_pool) when fusing, else out, out being
     (B, KV, S*g, hd)."""
     fuse = k_new is not None
     B, KV, Sg, hd = q.shape
     S = q_pos.shape[1]
     g = Sg // S
-    psz = k_pool.shape[1]
+    psz = k_pool.shape[2]
     n_steps = block_table.shape[1]
     scale = 1.0 / (hd ** 0.5)
+    # row r of the query block is token r // g: its position, as a column
+    # the mask broadcasts against (vector reads of the scalar-prefetched
+    # operands are not allowed, so this rides in as a VMEM block)
+    row_pos = jnp.broadcast_to(q_pos[:, :, None], (B, S, g)).reshape(
+        B, Sg, 1)
 
     kernel = functools.partial(
         _kernel, scale=scale, page_size=psz, n_steps=n_steps, tile_k=tile_k,
-        window=window, S=S, g=g, T=ring_len, fuse=fuse)
+        window=window, S=S, T=ring_len, fuse=fuse)
 
+    rpos_spec = pl.BlockSpec((1, Sg, 1), lambda b, kv, ip, bt, lp: (b, 0, 0))
     q_spec = pl.BlockSpec((1, 1, Sg, hd),
-                          lambda b, kv, ip, bt, qp, lp: (b, kv, 0, 0))
+                          lambda b, kv, ip, bt, lp: (b, kv, 0, 0))
     new_spec = pl.BlockSpec((1, 1, S, hd),
-                            lambda b, kv, ip, bt, qp, lp: (b, kv, 0, 0))
+                            lambda b, kv, ip, bt, lp: (b, kv, 0, 0))
     # the dynamic gather (and scatter, when fusing): the page tile this
     # grid step streams is chosen by the prefetched block table
     pool_spec = pl.BlockSpec(
-        (1, psz, 1, hd), lambda b, kv, ip, bt, qp, lp: (bt[b, ip], 0, kv, 0))
+        (1, 1, psz, hd), lambda b, kv, ip, bt, lp: (bt[b, ip], kv, 0, 0))
     o_spec = pl.BlockSpec((1, 1, Sg, hd),
-                          lambda b, kv, ip, bt, qp, lp: (b, kv, 0, 0))
+                          lambda b, kv, ip, bt, lp: (b, kv, 0, 0))
 
-    in_specs = [q_spec] + ([new_spec, new_spec] if fuse else []) + \
-        [pool_spec, pool_spec]
+    in_specs = [rpos_spec, q_spec] + ([new_spec, new_spec] if fuse else []) \
+        + [pool_spec, pool_spec]
     out_specs = o_spec
     out_shape = jax.ShapeDtypeStruct((B, KV, Sg, hd), q.dtype)
     kwargs = {}
@@ -203,25 +229,25 @@ def paged_attention_grouped(q, k_new, v_new, k_pool, v_pool, block_table,
                      jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
                      jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)]
         # alias the pool inputs onto the pool outputs (in-place update;
-        # indices count ALL flat inputs including the 3 scalar-prefetch
-        # operands: bt=0, q_pos=1, last=2, q=3, k_new=4, v_new=5, pools)
+        # indices count ALL flat inputs including the 2 scalar-prefetch
+        # operands: bt=0, last=1, row_pos=2, q=3, k_new=4, v_new=5, pools)
         kwargs["input_output_aliases"] = {6: 1, 7: 2}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(B, KV, n_steps),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((Sg, hd), jnp.float32),          # acc
-            pltpu.VMEM((Sg,), jnp.float32),             # m (running max)
-            pltpu.VMEM((Sg,), jnp.float32),             # l (running sum)
+            pltpu.VMEM((Sg, 1), jnp.float32),           # m (running max)
+            pltpu.VMEM((Sg, 1), jnp.float32),           # l (running sum)
             pltpu.VMEM((tile_k * psz, hd), jnp.float32),  # K tile buffer
             pltpu.VMEM((tile_k * psz, hd), jnp.float32),  # V tile buffer
         ],
     )
 
-    args = (block_table, q_pos, last_pos, q) + \
+    args = (block_table, last_pos, row_pos, q) + \
         ((k_new, v_new) if fuse else ()) + (k_pool, v_pool)
     return pl.pallas_call(
         kernel,

@@ -28,26 +28,30 @@ def ring_positions(last, T):
     return last[:, None] - ((last[:, None] - idx[None, :]) % T)  # (B, T)
 
 
+def gather_ring(pool, block_table):
+    """Each slot's logical ring out of a (n_pages, KV, page_size, hd) pool:
+    ring entry i lives in row i % page_size of page block_table[b, i //
+    page_size].  Returns (B, T, KV, hd)."""
+    psz = pool.shape[2]
+    ring = jnp.arange(block_table.shape[1] * psz)
+    return pool[block_table[:, ring // psz], :, ring % psz]
+
+
 def reference_paged_attention(q, k_pool, v_pool, block_table, last_pos, *,
                               window: int = 0):
     """q: (B, H, hd) — ONE query token per slot, at position last_pos[b].
 
-    k_pool/v_pool: (n_pages, page_size, KV, hd) shared pools, the new
+    k_pool/v_pool: (n_pages, KV, page_size, hd) shared pools, the new
     token's K/V already scattered in.  block_table: (B, P) int32 page ids
     (page 0 = reserved null page).  last_pos: (B,) int32.  Returns
     (B, H, hd) in q's dtype."""
     B, H, hd = q.shape
-    psz = k_pool.shape[1]
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     g = H // KV
-    T = block_table.shape[1] * psz
+    T = block_table.shape[1] * k_pool.shape[2]
 
-    ring = jnp.arange(T)
-    g_idx = block_table[:, ring // psz] * psz + ring % psz       # (B, T)
-    flat_k = k_pool.reshape((-1,) + k_pool.shape[2:])
-    flat_v = v_pool.reshape((-1,) + v_pool.shape[2:])
-    ck = flat_k[g_idx].astype(jnp.float32)                       # (B, T, KV, hd)
-    cv = flat_v[g_idx].astype(jnp.float32)
+    ck = gather_ring(k_pool, block_table).astype(jnp.float32)    # (B, T, KV, hd)
+    cv = gather_ring(v_pool, block_table).astype(jnp.float32)
 
     k_pos = ring_positions(last_pos, T)
     valid = (k_pos >= 0) & (k_pos <= last_pos[:, None])
@@ -73,15 +77,12 @@ def reference_paged_attention_block(q, k_pool, v_pool, block_table,
     the per-row k_pos <= q_pos mask).  K/V for every row must already be
     in the pool.  Returns (B, S, H, hd) in q's dtype."""
     B, S, H, hd = q.shape
-    psz = k_pool.shape[1]
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[1]
     g = H // KV
-    T = block_table.shape[1] * psz
+    T = block_table.shape[1] * k_pool.shape[2]
 
-    ring = jnp.arange(T)
-    g_idx = block_table[:, ring // psz] * psz + ring % psz       # (B, T)
-    ck = k_pool.reshape((-1,) + k_pool.shape[2:])[g_idx].astype(jnp.float32)
-    cv = v_pool.reshape((-1,) + v_pool.shape[2:])[g_idx].astype(jnp.float32)
+    ck = gather_ring(k_pool, block_table).astype(jnp.float32)    # (B, T, KV, hd)
+    cv = gather_ring(v_pool, block_table).astype(jnp.float32)
 
     if q_positions is None:
         q_positions = last_pos[:, None] - (S - 1) + jnp.arange(S)[None, :]
@@ -109,17 +110,14 @@ def reference_paged_update(q, k_new, v_new, k_pool, v_pool, block_table,
     exact XLA write models/layers.py does — then block attention reads
     them back.  Returns (out, k_pool, v_pool)."""
     B, S = q.shape[:2]
-    psz = k_pool.shape[1]
+    psz = k_pool.shape[2]
     T = block_table.shape[1] * psz
     abs_pos = last_pos[:, None] - (S - 1) + jnp.arange(S)[None, :]
     slots = abs_pos % T
-    b_idx = jnp.arange(B)[:, None]
-    w_idx = block_table[b_idx, slots // psz] * psz + slots % psz  # (B, S)
-    flat = (-1,) + k_pool.shape[2:]
-    kp = k_pool.reshape(flat).at[w_idx].set(
-        k_new.astype(k_pool.dtype)).reshape(k_pool.shape)
-    vp = v_pool.reshape(flat).at[w_idx].set(
-        v_new.astype(v_pool.dtype)).reshape(v_pool.shape)
+    page = jnp.take_along_axis(block_table, slots // psz, axis=1)  # (B, S)
+    row = slots % psz
+    kp = k_pool.at[page, :, row].set(k_new.astype(k_pool.dtype))
+    vp = v_pool.at[page, :, row].set(v_new.astype(v_pool.dtype))
     out = reference_paged_attention_block(
         q, kp, vp, block_table, last_pos, window=window,
         q_positions=q_positions)

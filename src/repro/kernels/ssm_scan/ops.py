@@ -11,11 +11,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.ssm_scan.ssm_scan import ssm_scan_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -31,7 +28,7 @@ def ssm_scan(q, k, v, ld, u=None, state=None, chunk: int = 128):
     uu = u if bonus else jnp.zeros((H, Dk), jnp.float32)
     tr = lambda a: jnp.transpose(a, (0, 2, 1, 3))
     y = ssm_scan_bhsd(tr(q), tr(k), tr(v), tr(ld), uu, chunk=chunk,
-                      bonus=bonus, interpret=not _on_tpu())
+                      bonus=bonus, interpret=not on_tpu())
     # final state: one closed-form pass (exact, cheap relative to the scan)
     f32 = jnp.float32
     cum = jnp.cumsum(ld.astype(f32), axis=1)

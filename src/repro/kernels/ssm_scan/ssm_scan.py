@@ -38,12 +38,16 @@ def _kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, y_ref, state_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    u = u_ref[0].astype(jnp.float32) if bonus else None  # (Dk,)
+    u = u_ref[0].astype(jnp.float32) if bonus else None  # (1, Dk)
     sub = min(SUB, chunk)
     n_sub = chunk // sub
     ii = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
     causal = (jj < ii) if bonus else (jj <= ii)
+    # inclusive prefix sum along time as a lower-triangular matmul (Mosaic
+    # has no cumsum); HIGHEST keeps the f32 log-decays at f32 precision
+    tril = (jj <= ii).astype(jnp.float32)
+    ones_col = jnp.ones((sub, 1), jnp.float32)
 
     s = state_ref[...]                     # (Dk, Dv) fp32
     for b in range(n_sub):                 # static unroll: mini-scan with
@@ -52,22 +56,29 @@ def _kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, y_ref, state_ref,
         k = k_ref[0, 0, sl].astype(jnp.float32)
         v = v_ref[0, 0, sl].astype(jnp.float32)    # (sub, Dv)
         ld = ld_ref[0, 0, sl].astype(jnp.float32)
-        cum = jnp.cumsum(ld, axis=0)               # (sub, Dk)
+        cum = jnp.dot(tril, ld, precision=jax.lax.Precision.HIGHEST)  # (sub, Dk)
         # bonus (RWKV) reads s_{t-1}: query-side decay excludes step t
         cum_q = cum - ld if bonus else cum
         # intra: pairwise exp(cum_i - cum_j) has exponent <= 0 for j <= i —
         # stable for any decay strength (the qd/kd matmul factorization
-        # overflows fp32 beyond |cum| ~ 40)
-        diff = cum_q[:, None, :] - cum[None, :, :]  # (sub, sub, Dk)
-        diff = jnp.where(causal[:, :, None], diff, -jnp.inf)
-        A = jnp.sum(q[:, None, :] * k[None, :, :] * jnp.exp(diff), axis=-1)
-        y = A @ v
-        y = y + (q * jnp.exp(cum_q)) @ s           # exp(cum_q) <= 1
+        # overflows fp32 beyond |cum| ~ 40).  One key row j at a time, so
+        # every operand stays a 2-D (sub, Dk) tile: column j of the (sub,
+        # sub) score matrix times value row j
+        y = (q * jnp.exp(cum_q)) @ s               # exp(cum_q) <= 1
+        for j in range(sub):
+            row = slice(j, j + 1)
+            diff = jnp.where(causal[:, row], cum_q - cum[row], -jnp.inf)
+            a_j = jnp.sum(q * k[row] * jnp.exp(diff), axis=1, keepdims=True)
+            y = y + a_j * v[row]
         if bonus:
-            y = y + jnp.sum(q * u[None, :] * k, axis=1, keepdims=True) * v
-        total = cum[-1]                            # (Dk,)
-        k_carry = k * jnp.exp(total[None, :] - cum)  # exponent <= 0
-        s = (s * jnp.exp(total)[:, None]
+            y = y + jnp.sum(q * u * k, axis=1, keepdims=True) * v
+        total = cum[sub - 1:]                      # (1, Dk)
+        # the same total as a (Dk, 1) column, to decay the state's rows
+        total_col = jax.lax.dot_general(
+            ld, ones_col, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST)
+        k_carry = k * jnp.exp(total - cum)         # exponent <= 0
+        s = (s * jnp.exp(total_col)
              + jax.lax.dot_general(k_carry, v, (((0,), (0,)), ((), ()))))
         y_ref[0, 0, sl] = y.astype(y_ref.dtype)
     state_ref[...] = s
@@ -75,9 +86,10 @@ def _kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, y_ref, state_ref,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "bonus", "interpret"))
 def ssm_scan_bhsd(q, k, v, ld, u, *, chunk: int = 128, bonus: bool = False,
-                  interpret=True):
+                  interpret: bool):
     """q/k/ld: (B, H, S, Dk), v: (B, H, S, Dv), u: (H, Dk) (ignored unless
-    `bonus`).  Returns y (B, H, S, Dv)."""
+    `bonus`).  interpret: run the Pallas interpreter instead of lowering to
+    Mosaic (CPU tests).  Returns y (B, H, S, Dv)."""
     B, H, S, Dk = q.shape
     Dv = v.shape[-1]
     C = min(chunk, S)
@@ -94,10 +106,12 @@ def ssm_scan_bhsd(q, k, v, ld, u, *, chunk: int = 128, bonus: bool = False,
             pl.BlockSpec((1, 1, C, Dk), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, C, Dv), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, C, Dk), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, Dk), lambda b, h, c: (h, 0)),
+            # one head's bonus row as a (1, Dk) tile: a block's last two
+            # dims must be (8, 128)-aligned or whole, so u rides as (H, 1, Dk)
+            pl.BlockSpec((1, 1, Dk), lambda b, h, c: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, C, Dv), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), v.dtype),
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, ld, u)
+    )(q, k, v, ld, u.reshape(H, 1, Dk))

@@ -35,6 +35,10 @@ from repro.configs import get_config, model_archs
 from repro.configs.shapes import SHAPES
 from repro.launch import roofline as RL
 from repro.launch.mesh import make_production_mesh
+
+# the chip the production meshes stand for: the roofline terms divide by
+# its peaks (launch/mesh.PEAKS), whatever devices compile the dry run
+TARGET_KIND = "TPU v5 lite"
 from repro.launch.specs import (abstract_sharded_params, input_specs,
                                 shape_variant)
 
@@ -205,10 +209,7 @@ def run_combo(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
                                       rules=rules, zero1=zero1)
             with mesh:
                 compiled = jax.jit(fn).lower(*args).compile()
-            cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # older jax: per-device list
-                cost = cost[0] if cost else {}
-            cost = cost or {}
+            cost = compiled.cost_analysis() or {}
             coll = RL.collective_bytes(compiled.as_text())
             return compiled, cost, coll
 
@@ -246,11 +247,12 @@ def run_combo(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         n_dev = mesh.devices.size
         mf = RL.model_flops_per_device(
             cfg, shape, n_dev, backward=shape.kind == "train")
-        rl = RL.roofline_terms(cost_c, coll_c, mf)
+        rl = RL.roofline_terms(cost_c, coll_c, mf, device_kind=TARGET_KIND)
         rec.update(
             ok=True,
             seconds=round(time.time() - t0, 1),
             n_devices=n_dev,
+            device_kind=TARGET_KIND,
             memory={
                 "argument_bytes": mem.argument_size_in_bytes,
                 "output_bytes": mem.output_size_in_bytes,

@@ -1,6 +1,7 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), v5e constants (launch/mesh.py):
+Three terms per (arch x shape x mesh), from the peaks of the device kind
+the compile targets (launch/mesh.PEAKS; TPU v5e shown):
 
     compute s    = per-device HLO FLOPs / 197 TFLOP/s
     memory s     = per-device HLO bytes accessed / 819 GB/s
@@ -17,7 +18,7 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, asdict
 
-from repro.launch.mesh import HW
+from repro.launch.mesh import peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -70,14 +71,10 @@ def collective_bytes(hlo_text: str) -> dict:
             continue
         if op.endswith("-done"):
             continue  # counted at -start
-        # operand lists are typed in recent HLO text ("f32[8,64]{1,0} %x");
-        # sum the operand types directly, falling back to the symbol table
-        # for untyped "%x"-style references from older dumps
-        n = _type_bytes(args)
-        if n == 0:
-            for name in re.findall(r"%?([\w.\-]+)", args):
-                n += sizes.get(name, 0)
-        out[base] += n
+        # operands are bare "%x" references: size each from the symbol
+        # table (the argument list's other tokens are not instruction names)
+        out[base] += sum(sizes.get(name, 0)
+                         for name in re.findall(r"%([\w.\-]+)", args))
         counts[base] += 1
     out["total"] = sum(out[k] for k in COLLECTIVES)
     out["counts"] = counts
@@ -100,13 +97,16 @@ class Roofline:
         return asdict(self)
 
 
-def roofline_terms(cost: dict, coll: dict, model_flops: float) -> Roofline:
+def roofline_terms(cost: dict, coll: dict, model_flops: float, *,
+                   device_kind: str) -> Roofline:
+    """The three terms against the peaks of one `device_kind` chip."""
+    hw = peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     ba = float(cost.get("bytes accessed", 0.0))
     cb = float(coll.get("total", 0))
-    compute_s = flops / HW["peak_flops_bf16"]
-    memory_s = ba / HW["hbm_bw"]
-    collective_s = cb / HW["ici_link_bw"]
+    compute_s = flops / hw["peak_flops_bf16"]
+    memory_s = ba / hw["hbm_bw"]
+    collective_s = cb / hw["ici_link_bw"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)

@@ -54,7 +54,9 @@ def _parse_mesh(spec: str):
             f"--mesh {spec} needs {need} devices but only {have} are "
             f"visible (set XLA_FLAGS=--xla_force_host_platform_device_"
             f"count={need} to debug on CPU)")
-    return jax.make_mesh((d, m), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh((d, m), ("data", "model"))
 
 
 def _serving_config(args, cfg, telemetry=None):
@@ -303,7 +305,10 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import params as Pm
+
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params, _ = Pm.init_params(jax.random.PRNGKey(0), cfg)

@@ -17,6 +17,8 @@ import time
 
 import jax
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def run_gtl_paper(args):
     """--arch gtl_paper: the faithful reproduction path."""
@@ -51,6 +53,7 @@ def main():
     ap.add_argument("--scenario", default="hapt")
     ap.add_argument("--samples", type=int, default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.arch in ("gtl_paper", "gtl-paper"):
         return run_gtl_paper(args)

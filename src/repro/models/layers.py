@@ -167,7 +167,7 @@ def attention_block(p, x, cfg: ModelConfig, *, positions=None, cache=None,
     cache dict, in one of two layouts:
       - dense: {"k": (B, T, KV, hd), "v": ..., "pos": int32 current length}
         — each lane owns a T-entry ring;
-      - paged: {"k": (n_pages, page_size, KV, hd), "v": ... (shared pools),
+      - paged: {"k": (n_pages, KV, page_size, hd), "v": ... (shared pools),
         "block_table": (B, P) int32 page ids, "pos": ...} — lanes address a
         shared page pool through their block table; the logical ring is
         P * page_size entries.
@@ -276,7 +276,7 @@ def attention_block(p, x, cfg: ModelConfig, *, positions=None, cache=None,
             # null page 0; its (garbage) entries sit at ring indices past
             # `last` and are cut by the validity mask either way.
             bt = cache["block_table"]  # (B, P) page ids
-            psz = cache["k"].shape[1]
+            psz = cache["k"].shape[2]
             T = bt.shape[1] * psz
             if (paged_kernel == "pallas" and shard is None
                     and not cfg.mrope and not cfg.chunked_attention
@@ -293,21 +293,21 @@ def attention_block(p, x, cfg: ModelConfig, *, positions=None, cache=None,
                     window=cfg.sliding_window,
                     q_positions=None if default_pos else positions)
             else:
+                # pool (n_pages, KV, psz, hd): ring slot i of lane b is
+                # row i % psz of page bt[b, i // psz]
                 slots = abs_pos % T
-                flat = (-1,) + cache["k"].shape[2:]
-                w_idx = bt[b_idx, slots // psz] * psz + slots % psz  # (B, S)
-                fk = cache["k"].reshape(flat).at[w_idx].set(
+                page = bt[b_idx, slots // psz]  # (B, S)
+                store_k = cache["k"].at[page, :, slots % psz].set(
                     k.astype(kv_dtype))
-                fv = cache["v"].reshape(flat).at[w_idx].set(
+                store_v = cache["v"].at[page, :, slots % psz].set(
                     v.astype(kv_dtype))
-                store_k = fk.reshape(cache["k"].shape)
-                store_v = fv.reshape(cache["v"].shape)
-                if shard is not None:  # pool: (n_pages, psz, KV, hd)
-                    store_k = shard.act(store_k, heads=2)
-                    store_v = shard.act(store_v, heads=2)
+                if shard is not None:
+                    store_k = shard.act(store_k, heads=1)
+                    store_v = shard.act(store_v, heads=1)
                 ring = jnp.arange(T)
-                g_idx = bt[:, ring // psz] * psz + ring % psz  # (B, T)
-                ck, cv = fk[g_idx], fv[g_idx]  # (B, T, KV, hd)
+                ring_page, ring_row = bt[:, ring // psz], ring % psz
+                ck = store_k[ring_page, :, ring_row]  # (B, T, KV, hd)
+                cv = store_v[ring_page, :, ring_row]
                 if shard is not None:
                     ck = shard.act(ck, batch=0, heads=2)
                     cv = shard.act(cv, batch=0, heads=2)

@@ -27,7 +27,7 @@ from repro.models.config import ModelConfig
 
 
 class ForwardOut(NamedTuple):
-    logits: jax.Array           # (B, S, V) or (B, S, codebooks, V)
+    logits: jax.Array           # fp32 (B, S, V) or (B, S, codebooks, V)
     cache: Any                  # None for training
     aux_loss: jax.Array         # MoE load-balance loss (0.0 otherwise)
 
@@ -127,11 +127,16 @@ def mrope_positions(cfg: ModelConfig, batch: int, seq: int):
 
 
 def unembed(params, cfg: ModelConfig, h):
+    """Logits in fp32, straight from the dot's fp32 accumulator: rounding
+    them to a bf16 model dtype would quantize every top1-top2 gap to the
+    bf16 spacing of the top logit (1/64 near 3), so the tie margins the
+    serving engines record could not tell a tie from a clear choice."""
+    f32 = jnp.float32
     if cfg.num_codebooks > 1:
-        return jnp.einsum("bsd,cdv->bscv", h, params["lm_head"])
-    if cfg.tie_embeddings:
-        return h @ params["embed"]["tok"].T
-    return h @ params["lm_head"]
+        return jnp.einsum("bsd,cdv->bscv", h, params["lm_head"],
+                          preferred_element_type=f32)
+    w = params["embed"]["tok"].T if cfg.tie_embeddings else params["lm_head"]
+    return jnp.matmul(h, w, preferred_element_type=f32)
 
 
 # ---------------------------------------------------------------- forward

@@ -14,7 +14,7 @@ Three engines share the same narrow surface (`mark_reset`, `admit`,
 - ``DenseEngine``: one (n_slots, capacity, KV, hd) ring per layer; "pos"
   lives on device as a (n_slots,) vector inside the cache tree; slot
   resets are fused into the decode dispatch via a reset mask.
-- ``PagedEngine``: ONE shared (n_pages, page_size, KV, hd) pool per layer
+- ``PagedEngine``: ONE shared (n_pages, KV, page_size, hd) pool per layer
   addressed through a host-owned (n_slots, pages_per_slot) block table;
   positions are host-tracked, page lifetime belongs to the policy layer's
   PageAllocator — the engine only writes table rows and scatters through
@@ -49,7 +49,7 @@ from repro.serving.kvcache import (DEFAULT_PAGE_SIZE, attn_cache_shape,
                                    init_cache, init_paged_cache,
                                    paged_attn_layout)
 from repro.serving.sampling import (SlotSampling, argmax_with_margin,
-                                    row_scores, token_logprob)
+                                    row_sample, token_logprob)
 from repro.serving.serve_step import (make_engine_step,
                                       make_paged_engine_step,
                                       make_paged_prefill_step,
@@ -171,9 +171,13 @@ class DenseEngine:
         """One fused tick: every slot advances one token in ONE dispatch."""
         with (self.telemetry.annotate("dense.decode")
               if self.telemetry is not None else _NULL):
+            # jnp.array, not jnp.asarray: the mask is engine state rewritten
+            # below while the dispatch may still run, and on the CPU
+            # backend jnp.asarray can alias a numpy buffer instead of
+            # copying it
             nxt, margins, logps, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(self._reset_mask), jnp.asarray(active_mask),
+                jnp.array(self._reset_mask), jnp.asarray(active_mask),
                 sampling)
         self.decode_dispatches += 1
         self._reset_mask[:] = False
@@ -335,11 +339,14 @@ class PagedEngine:
     def decode(self, toks, active_mask, sampling: SlotSampling):
         with (self.telemetry.annotate("paged.decode")
               if self.telemetry is not None else _NULL):
+            # copies of the engine's host state (see DenseEngine.decode):
+            # positions and masks are rewritten below, before the
+            # dispatch's results are read
             nxt, margins, logps, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(self.slot_pos), jnp.asarray(self.block_table),
-                jnp.asarray(self._reset_mask), jnp.asarray(self._copy_src),
-                jnp.asarray(self._copy_dst), sampling)
+                jnp.array(self.slot_pos), jnp.array(self.block_table),
+                jnp.array(self._reset_mask), jnp.array(self._copy_src),
+                jnp.array(self._copy_dst), sampling)
         self.decode_dispatches += 1
         self._reset_mask[:] = False
         self._copy_src[:] = 0
@@ -384,8 +391,8 @@ class PerSlotEngine:
             out = T.forward(params, cfg, tok, cache=cache,
                             use_pallas=use_pallas)
             logits = out.logits[0, -1]
-            scores = row_scores(logits, row)
-            tok_, margin = argmax_with_margin(scores[None])
+            scores, cut = row_sample(logits, row)
+            tok_, margin = argmax_with_margin(scores[None], cut[None])
             logprob = token_logprob(logits[None], tok_)
             return tok_[0], margin[0], logprob[0], out.cache
 

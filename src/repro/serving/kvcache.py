@@ -7,7 +7,7 @@ Two attention-cache layouts:
 - dense (``init_cache``): one ``(batch, capacity, KV, hd)`` ring per layer —
   every slot owns worst-case ``capacity`` entries whether it uses them or
   not;
-- paged (``init_paged_cache``): ONE shared ``(n_pages, page_size, KV, hd)``
+- paged (``init_paged_cache``): ONE shared ``(n_pages, KV, page_size, hd)``
   pool per layer, addressed through per-slot block tables of page ids
   (vLLM-style).  Slots consume pages proportional to their actual sequence
   length, and slots with a common prompt prefix can refcount the same pages
@@ -209,7 +209,7 @@ def init_paged_cache(cfg: ModelConfig, n_slots: int, capacity: int,
     dtype = dtype or jnp.dtype(cfg.dtype)
     kv_dtype = jnp.dtype(cfg.kv_cache_dtype) if cfg.kv_cache_dtype else dtype
     L = cfg.n_layers
-    pool = (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    pool = (n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
 
     if cfg.block_kind == "attention":
         return {"layers": {"k": jnp.zeros((L,) + pool, kv_dtype),
@@ -312,7 +312,7 @@ def cow_copy_pages(cfg: ModelConfig, cache, copy_src, copy_dst):
         def one(ax, a):
             if ax >= 0:
                 return a  # per-slot dense lanes: never shared, never CoW'd
-            # pool leaves are (..., n_pages, page_size, KV, hd): page axis
+            # pool leaves are (..., n_pages, KV, page_size, hd): page axis
             # is -4.  Duplicate dst=0 rows all write page 0 with page 0's
             # own contents, so scatter order does not matter.
             moved = jnp.moveaxis(a, -4, 0)
@@ -343,7 +343,7 @@ def reset_paged_sub(cfg: ModelConfig, sub, reset):
 #   vector) shards over the data axes; attention K/V leaves additionally
 #   shard their KV-head axis over "model";
 # - paged pool: per-slot (hybrid recurrent) leaves shard their slot axis
-#   over data; the shared (n_pages, page_size, KV, hd) pools shard the
+#   over data; the shared (n_pages, KV, page_size, hd) pools shard the
 #   KV-head axis over "model" and REPLICATE over data — any slot's block
 #   table may point at any page, so the page axis cannot follow the slots;
 # - divisibility fallback everywhere: a dim shards only when the mesh axis
@@ -388,9 +388,9 @@ def paged_cache_shardings(cfg: ModelConfig, cache, mesh, *,
         if ax >= 0:  # per-slot dense lanes (hybrid recurrent state)
             if ds > 1 and leaf.shape[ax] % ds == 0:
                 spec[ax] = tuple(data_axes)
-        elif ms > 1 and leaf.shape[-2] % ms == 0:
-            # shared pool (..., n_pages, page_size, KV, hd): KV at -2
-            spec[-2] = model_axis
+        elif ms > 1 and leaf.shape[-3] % ms == 0:
+            # shared pool (..., n_pages, KV, page_size, hd): KV at -3
+            spec[-3] = model_axis
         return NamedSharding(mesh, P(*spec))
 
     return jax.tree.map(one, axes, cache)

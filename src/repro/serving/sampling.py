@@ -9,7 +9,9 @@ which lets every engine reuse the greedy machinery — the token is
 ``argmax(scores)`` and the top1-top2 gap of the SAME scores is the tie
 margin that ``completions_equivalent`` already understands (a near-zero
 margin marks a perturbed-score tie where differently-compiled variants of
-the same math may legitimately pick different tokens).  At
+the same math may legitimately pick different tokens).  A top-k / top-p
+filter is a second place where they may: when moving its cut could change
+the token, the margin is at most how far the cut is from moving.  At
 ``temperature <= 0`` the scores ARE the raw fp32 logits, so the greedy
 path is recovered bit-for-bit and a whole-batch ``lax.cond`` skips the
 sampling compute entirely when no slot samples.
@@ -122,6 +124,22 @@ def _gumbel(key, step, V):
                              jnp.float32)
 
 
+class _Cut(NamedTuple):
+    """Where a top-k / top-p filter cut the ranked vocabulary: the lowest
+    survivor and the highest dropped token (ids and scaled logits; the
+    dropped logit is -inf when nothing is dropped), and, for a nucleus
+    cut, how near the mass ranked before each is to top_p, in margin units
+    (a nudge of e moves a softmax mass by at most e / 2, and a margin is a
+    gap, twice the nudge; inf without a nucleus)."""
+
+    last: jax.Array
+    first: jax.Array
+    last_logit: jax.Array
+    first_logit: jax.Array
+    mass_last: jax.Array
+    mass_first: jax.Array
+
+
 def _filter_keep(scaled, top_k, top_p):
     """Boolean keep mask over (V,) scaled logits: top-k first, then the
     nucleus cut over the RENORMALIZED top-k survivors (HF/vLLM order) —
@@ -129,7 +147,7 @@ def _filter_keep(scaled, top_k, top_p):
     token that crosses the threshold is kept).  Masks are rank-based, not
     value-threshold-based: exactly k (resp. n_keep) tokens survive even
     when the cutoff logit is tied (stable argsort breaks ties toward the
-    lower index, matching argmax)."""
+    lower index, matching argmax).  Returns (keep, _Cut)."""
     V = scaled.shape[-1]
     order = jnp.argsort(-scaled)  # descending, stable
     ranks = jnp.zeros((V,), jnp.int32).at[order].set(
@@ -137,22 +155,51 @@ def _filter_keep(scaled, top_k, top_p):
     k = jnp.where(top_k > 0, jnp.minimum(top_k, V), V)
     srt = scaled[order]
     probs = jax.nn.softmax(jnp.where(jnp.arange(V) < k, srt, -jnp.inf))
-    n_keep = jnp.maximum(1, jnp.sum((jnp.cumsum(probs) - probs) < top_p))
+    before = jnp.cumsum(probs) - probs
+    n_keep = jnp.maximum(1, jnp.sum(before < top_p))
     n_p = jnp.where(top_p < 1.0, n_keep, V)
-    return (ranks < k) & (ranks < n_p)
+    n = jnp.minimum(k, n_p)  # survivors
+    nxt = jnp.minimum(n, V - 1)
+    nucleus = top_p < 1.0
+    cut = _Cut(order[n - 1], order[nxt], srt[n - 1],
+               jnp.where(n < V, srt[nxt], -jnp.inf),
+               jnp.where(nucleus, 4.0 * (top_p - before[n - 1]), jnp.inf),
+               jnp.where(nucleus & (n_p < k), 4.0 * (before[nxt] - top_p),
+                         jnp.inf))
+    return (ranks < k) & (ranks < n_p), cut
+
+
+def _sampled(logits, key, step, temperature, top_k, top_p):
+    """sampled_scores plus the filter's cut margin: how near the cut is
+    to changing the sampled token, to first order.  The winner leaves when
+    the highest dropped token overtakes it (or, as the lowest survivor,
+    when the nucleus mass before it reaches top_p); a dropped token that
+    outscores the winner enters when it overtakes the lowest survivor (or,
+    as the highest dropped, when the mass before it falls below top_p)."""
+    logits = logits.astype(jnp.float32)
+    scaled = _scaled(logits, temperature)
+    keep, cut = _filter_keep(scaled, top_k, top_p)
+    noisy = scaled + _gumbel(key, step, logits.shape[-1])
+    perturbed = jnp.where(keep, noisy, -jnp.inf)
+    best = jnp.argmax(perturbed)
+    leave = scaled[best] - cut.first_logit
+    leave = jnp.where(best == cut.last, jnp.minimum(leave, cut.mass_last),
+                      leave)
+    ids = jnp.arange(scaled.shape[-1])
+    enter = jnp.where(ids == cut.first,
+                      jnp.minimum(cut.last_logit - scaled, cut.mass_first),
+                      cut.last_logit - scaled)
+    enter = jnp.where(~keep & (noisy > perturbed[best]), enter, jnp.inf)
+    sampled = temperature > 0
+    return (jnp.where(sampled, perturbed, logits),
+            jnp.where(sampled, jnp.minimum(leave, jnp.min(enter)), jnp.inf))
 
 
 def sampled_scores(logits, key, step, temperature, top_k, top_p):
     """(V,) logits + scalar params -> (V,) fp32 scores whose argmax is the
     sampled token (Gumbel-max); temperature <= 0 returns the raw fp32
     logits, so argmax recovers greedy bit-for-bit."""
-    logits = logits.astype(jnp.float32)
-    scaled = _scaled(logits, temperature)
-    keep = _filter_keep(scaled, top_k, top_p)
-    perturbed = jnp.where(keep, scaled + _gumbel(key, step,
-                                                 logits.shape[-1]),
-                          -jnp.inf)
-    return jnp.where(temperature > 0, perturbed, logits)
+    return _sampled(logits, key, step, temperature, top_k, top_p)[0]
 
 
 def _temperature_scores(logits, key, step, temperature, top_k, top_p):
@@ -165,46 +212,65 @@ def _temperature_scores(logits, key, step, temperature, top_k, top_p):
     return jnp.where(temperature > 0, perturbed, logits)
 
 
+def _unfiltered(logits, key, step, temperature, top_k, top_p):
+    return (_temperature_scores(logits, key, step, temperature, top_k,
+                                top_p), jnp.float32(jnp.inf))
+
+
 def _filtered(top_k, top_p):
     return (top_k > 0) | (top_p < 1.0)
 
 
-def batched_scores(logits, sampling: SlotSampling):
-    """(B, V) logits + batched SlotSampling -> (B, V) scores.  Whole-batch
-    conds keep the common cases cheap: every-slot-greedy pays only the
-    argmax it always paid, and pure-temperature sampling skips the
-    top-k/top-p filter's full-vocab sort."""
-    greedy = logits.astype(jnp.float32)
+def batched_sample(logits, sampling: SlotSampling):
+    """(B, V) logits + batched SlotSampling -> ((B, V) scores, (B,) cut
+    margins; see `_sampled`).  Whole-batch conds keep the common cases
+    cheap: every-slot-greedy pays only the argmax it always paid, and
+    pure-temperature sampling skips the top-k/top-p filter's full-vocab
+    sort."""
+    greedy = (logits.astype(jnp.float32),
+              jnp.full(logits.shape[:1], jnp.inf, jnp.float32))
 
     def sample(_):
         return jax.lax.cond(
             jnp.any(_filtered(sampling.top_k, sampling.top_p)),
-            lambda __: jax.vmap(sampled_scores)(logits, *sampling),
-            lambda __: jax.vmap(_temperature_scores)(logits, *sampling),
+            lambda __: jax.vmap(_sampled)(logits, *sampling),
+            lambda __: jax.vmap(_unfiltered)(logits, *sampling),
             None)
 
     return jax.lax.cond(jnp.any(sampling.temperature > 0), sample,
                         lambda _: greedy, None)
 
 
-def row_scores(logits, row: SlotSampling):
-    """(V,) logits + scalar-leaf SlotSampling row -> (V,) scores (the
-    chunked-prefill steps sample one slot's first generated token)."""
+def batched_scores(logits, sampling: SlotSampling):
+    """(B, V) logits + batched SlotSampling -> (B, V) scores."""
+    return batched_sample(logits, sampling)[0]
+
+
+def row_sample(logits, row: SlotSampling):
+    """(V,) logits + scalar-leaf SlotSampling row -> ((V,) scores, cut
+    margin) (the chunked-prefill steps sample one slot's first generated
+    token)."""
 
     def sample(_):
         return jax.lax.cond(
             _filtered(row.top_k, row.top_p),
-            lambda __: sampled_scores(logits, *row),
-            lambda __: _temperature_scores(logits, *row), None)
+            lambda __: _sampled(logits, *row),
+            lambda __: _unfiltered(logits, *row), None)
 
-    return jax.lax.cond(row.temperature > 0, sample,
-                        lambda _: logits.astype(jnp.float32), None)
+    return jax.lax.cond(
+        row.temperature > 0, sample,
+        lambda _: (logits.astype(jnp.float32), jnp.float32(jnp.inf)), None)
 
 
-def argmax_with_margin(scores):
-    """(B, V) -> (argmax (B,), top1-top2 margin (B,) in fp32)."""
+def argmax_with_margin(scores, cut=None):
+    """(B, V) -> (argmax (B,), top1-top2 margin (B,) in fp32); with the
+    (B,) cut margins of `batched_sample`, the margin is the smaller of the
+    two, so a token a nudged filter cut would change counts as a tie."""
     top2 = jax.lax.top_k(scores.astype(jnp.float32), 2)[0]
-    return jnp.argmax(scores, axis=-1), top2[:, 0] - top2[:, 1]
+    margin = top2[:, 0] - top2[:, 1]
+    if cut is not None:
+        margin = jnp.minimum(margin, cut)
+    return jnp.argmax(scores, axis=-1), margin
 
 
 def token_logprob(logits, tok):

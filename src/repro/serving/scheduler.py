@@ -40,7 +40,7 @@ Cache layouts (`cache_layout=` on the fused engine):
 
   - "dense" (default): one (n_slots, capacity, KV, hd) ring per layer —
     every slot owns worst-case `capacity` entries for its whole lifetime;
-  - "paged": ONE shared (n_pages, page_size, KV, hd) pool per layer plus
+  - "paged": ONE shared (n_pages, KV, page_size, hd) pool per layer plus
     per-slot block tables of page ids (vLLM-style).  A `PageAllocator`
     owns page lifetime host-side; a request whose worst case can NEVER
     fit the pool is rejected at submit() instead of stalling the queue
@@ -354,8 +354,10 @@ class PageAllocator:
         return self._prefix.get(key)
 
     def register_prefix(self, key, pid: int):
-        """Publish a full prompt page for sharing (first writer wins)."""
-        if key not in self._prefix:
+        """Publish a full prompt page for sharing (first writer wins).  A
+        page publishes at most one key — the one `release` withdraws when
+        the page is freed; a second key would outlive the page."""
+        if key not in self._prefix and pid not in self._page_key:
             self._prefix[key] = pid
             self._page_key[pid] = key
 

@@ -21,8 +21,8 @@ from repro.serving.kvcache import (cow_copy_pages, paged_slot_slice,
                                    reset_paged_sub, reset_slots, slot_slice,
                                    slot_update)
 from repro.serving.sampling import (SamplingParams, argmax_with_margin,
-                                    batched_scores, lockstep_scores,
-                                    row_scores, token_logprob)
+                                    batched_sample, lockstep_scores,
+                                    row_sample, token_logprob)
 
 
 def make_serve_step(cfg: ModelConfig, use_pallas: bool = False):
@@ -94,10 +94,10 @@ def make_engine_step(cfg: ModelConfig, use_pallas: bool = False,
             # replicate the Gumbel-max region: sharding the legacy threefry
             # RNG would change the noise bits (see ShardingPlan.rep)
             logits = plan.rep(logits)
-        scores = batched_scores(logits, sampling)
+        scores, cut = batched_sample(logits, sampling)
         if plan is not None:
             scores = plan.rep(scores)
-        next_tok, margin = argmax_with_margin(scores)
+        next_tok, margin = argmax_with_margin(scores, cut)
         logprob = token_logprob(logits, next_tok)
         new_cache = dict(out.cache,
                          pos=jnp.where(active_mask, out.cache["pos"], pos0))
@@ -124,7 +124,7 @@ def make_paged_engine_step(cfg: ModelConfig, use_pallas: bool = False,
     branch's private page.
 
     cache: a paged pool cache (kvcache.init_paged_cache) — attention K/V in
-    shared (n_pages, page_size, KV, hd) pools, hybrid recurrent state in
+    shared (n_pages, KV, page_size, hd) pools, hybrid recurrent state in
     dense per-slot lanes.  pos: (n_slots,) int32, HOST-tracked (the
     scheduler knows each slot's fed count, so refill and prefix jump-start
     are host integer writes — idle lanes stay pinned by construction).
@@ -153,10 +153,10 @@ def make_paged_engine_step(cfg: ModelConfig, use_pallas: bool = False,
         logits = out.logits[:, -1]
         if plan is not None:
             logits = plan.rep(logits)
-        scores = batched_scores(logits, sampling)
+        scores, cut = batched_sample(logits, sampling)
         if plan is not None:
             scores = plan.rep(scores)
-        next_tok, margin = argmax_with_margin(scores)
+        next_tok, margin = argmax_with_margin(scores, cut)
         logprob = token_logprob(logits, next_tok)
         new_cache = {k: v for k, v in out.cache.items() if k != "pos"}
         return next_tok, margin, logprob, new_cache
@@ -191,10 +191,10 @@ def make_slot_prefill_step(cfg: ModelConfig, use_pallas: bool = False,
         logits = out.logits[0, -1]
         if plan is not None:
             logits = plan.rep(logits)
-        scores = row_scores(logits, row)
+        scores, cut = row_sample(logits, row)
         if plan is not None:
             scores = plan.rep(scores)
-        tok, margin = argmax_with_margin(scores[None])
+        tok, margin = argmax_with_margin(scores[None], cut[None])
         logprob = token_logprob(logits[None], tok)
         return tok[0], margin[0], logprob[0], cache
 
@@ -234,10 +234,10 @@ def make_paged_prefill_step(cfg: ModelConfig, use_pallas: bool = False,
         logits = out.logits[0, -1]
         if plan is not None:
             logits = plan.rep(logits)
-        scores = row_scores(logits, row)
+        scores, cut = row_sample(logits, row)
         if plan is not None:
             scores = plan.rep(scores)
-        tok, margin = argmax_with_margin(scores[None])
+        tok, margin = argmax_with_margin(scores[None], cut[None])
         logprob = token_logprob(logits[None], tok)
         return tok[0], margin[0], logprob[0], cache
 

@@ -24,6 +24,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.configs import get_smoke_config
     from repro.configs.shapes import InputShape
     from repro.launch import roofline as RL
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import input_specs, abstract_sharded_params
 
     out = {}
@@ -32,9 +33,9 @@ _SCRIPT = textwrap.dedent("""
                  "zamba2_2_7b"]:
         cfg = get_smoke_config(arch).replace(dtype="bfloat16")
         for multi in (False, True):
-            mesh = jax.make_mesh((2, 2, 2) if multi else (2, 4),
-                                 ("pod", "data", "model") if multi
-                                 else ("data", "model"))
+            mesh = make_mesh((2, 2, 2) if multi else (2, 4),
+                             ("pod", "data", "model") if multi
+                             else ("data", "model"))
             shape = InputShape("t", 64, 8, "train")
             fn, args = build_step(cfg, shape, mesh, multi_pod=multi)
             with mesh:
@@ -42,20 +43,18 @@ _SCRIPT = textwrap.dedent("""
             hlo = compiled.as_text()
             coll = RL.collective_bytes(hlo)
             key = f"{arch}:{'multi' if multi else 'single'}"
-            ca = compiled.cost_analysis()  # dict, or list of per-device dicts
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
+            ca = compiled.cost_analysis()
             out[key] = {"ok": True, "coll_total": coll["total"],
                         "flops": (ca or {}).get("flops", 0)}
         # decode on the single mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shape = InputShape("d", 64, 8, "decode")
         fn, args = build_step(cfg, shape, mesh, multi_pod=False)
         with mesh:
             compiled = jax.jit(fn).lower(*args).compile()
         out[arch + ":decode"] = {"ok": True}
     # sync step emits a cross-pod collective
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke_config("qwen3_0_6b").replace(dtype="bfloat16")
     fn, args = build_sync_step(cfg, mesh)
     with mesh:

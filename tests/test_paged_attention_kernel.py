@@ -15,8 +15,8 @@ PSZ = 16
 def _pool_setup(key, B, H, KV, hd, pages_per_slot, n_pages, psz=PSZ):
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, 1, H, hd))
-    k_pool = jax.random.normal(ks[1], (n_pages, psz, KV, hd))
-    v_pool = jax.random.normal(ks[2], (n_pages, psz, KV, hd))
+    k_pool = jax.random.normal(ks[1], (n_pages, KV, psz, hd))
+    v_pool = jax.random.normal(ks[2], (n_pages, KV, psz, hd))
     return q, k_pool, v_pool
 
 
@@ -133,16 +133,15 @@ def test_matches_layers_xla_gather_path():
     key = jax.random.PRNGKey(7)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, 1, H, hd))
-    k_pool = jax.random.normal(ks[1], (8, psz, KV, hd))
-    v_pool = jax.random.normal(ks[2], (8, psz, KV, hd))
+    k_pool = jax.random.normal(ks[1], (8, KV, psz, hd))
+    v_pool = jax.random.normal(ks[2], (8, KV, psz, hd))
     bt = jnp.array([[1, 2, 3], [4, 5, 6]], jnp.int32)
     last = jnp.array([18, 3], jnp.int32)
 
     T = P * psz
     ring = jnp.arange(T)
-    g_idx = bt[:, ring // psz] * psz + ring % psz
-    ck = k_pool.reshape(-1, KV, hd)[g_idx]
-    cv = v_pool.reshape(-1, KV, hd)[g_idx]
+    ck = k_pool[bt[:, ring // psz], :, ring % psz]  # (B, T, KV, hd)
+    cv = v_pool[bt[:, ring // psz], :, ring % psz]
     k_pos = pa_ref.ring_positions(last, T)
     mask = Lyr._attn_mask(last[:, None], k_pos) & (k_pos >= 0)[:, None, :]
     want = Lyr.multi_head_attention(q, ck, cv, mask, dtype=q.dtype)
@@ -159,8 +158,8 @@ def test_matches_layers_xla_gather_path():
 def _block_setup(key, B, S, H, KV, hd, n_pages, psz=PSZ):
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (B, S, H, hd))
-    k_pool = jax.random.normal(ks[1], (n_pages, psz, KV, hd))
-    v_pool = jax.random.normal(ks[2], (n_pages, psz, KV, hd))
+    k_pool = jax.random.normal(ks[1], (n_pages, KV, psz, hd))
+    v_pool = jax.random.normal(ks[2], (n_pages, KV, psz, hd))
     k_new = jax.random.normal(ks[3], (B, S, KV, hd))
     v_new = jax.random.normal(ks[4], (B, S, KV, hd))
     return q, k_pool, v_pool, k_new, v_new
@@ -380,7 +379,7 @@ def test_oversized_block_fails_loud():
     B, H, KV, hd, P = 1, 4, 2, 64, 2
     S = P * PSZ + 1
     q = jax.random.normal(jax.random.PRNGKey(20), (B, S, H, hd))
-    kp = jnp.zeros((4, PSZ, KV, hd))
+    kp = jnp.zeros((4, KV, PSZ, hd))
     bt = jnp.array([[1, 2]], jnp.int32)
     last = jnp.array([S - 1], jnp.int32)
     with pytest.raises(ValueError, match="ring"):

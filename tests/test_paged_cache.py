@@ -49,6 +49,21 @@ def test_allocator_refcounted_prefix_pages():
     assert al.lookup_prefix(key) is None and pid in al._free
 
 
+def test_allocator_page_publishes_one_prefix_key():
+    """A page registered under a second key keeps only the first: freeing
+    the page must leave no key that serves it (the falsifying example
+    hypothesis found for test_page_allocator_invariants)."""
+    al = PageAllocator(n_pages=2, page_size=4)
+    first, second = ((), (0,)), ((), (1,))
+    pid = al.alloc()
+    al.register_prefix(first, pid)
+    al.register_prefix(second, pid)
+    assert al.lookup_prefix(first) == pid
+    assert al.lookup_prefix(second) is None
+    al.release(pid)
+    assert al.lookup_prefix(first) is None and pid in al._free
+
+
 def test_pool_exhaustion_stalls_then_resumes(setup):
     """With a pool that fits one request at a time the queue must stall
     (not crash) and admission must resume as finished slots reclaim."""

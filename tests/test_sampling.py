@@ -149,6 +149,70 @@ def test_argmax_with_margin_infinite_when_single_survivor():
     assert int(tok[0]) == 0 and np.isinf(float(margin[0]))
 
 
+@jax.jit
+def _sample_one(logits, ss):
+    from repro.serving.sampling import batched_sample
+    return argmax_with_margin(*batched_sample(logits[None], ss))
+
+
+def _margin(logits, **kw):
+    ss = SlotSampling(*(np.asarray(x)[None] for x in _row(**kw)))
+    tok, margin = _sample_one(jnp.asarray(logits, jnp.float32), ss)
+    return int(tok[0]), float(margin[0])
+
+
+def test_margin_counts_a_top_k_cut_next_to_the_winner():
+    # top_k=1 keeps token 0 alone, 0.01 above the token it drops: a nudge
+    # of the logits swaps them, so the margin is that gap, not inf
+    logits = np.asarray([3.0, 2.99, -1.0, -2.0])
+    for seed in range(4):
+        tok, margin = _margin(logits, temperature=1.0, top_k=1, seed=seed)
+        assert tok == 0
+        np.testing.assert_allclose(margin, 0.01, rtol=1e-4)
+    # unfiltered sampling leaves the Gumbel gap alone
+    tok, margin = _margin(logits, temperature=1.0, seed=0)
+    assert margin > 0.0 and np.isfinite(margin)
+
+
+def test_margin_counts_a_nucleus_cut_next_to_top_p():
+    # top_p=0.52 keeps token 0 (mass p0 ~ 0.525) alone: the mass before
+    # token 1 is 0.005 past top_p, so a nudge of 2 * 0.005 admits it.
+    # That moves the sampled token only where token 1 outscores token 0
+    # once admitted; elsewhere token 1 must overtake token 0 (gap 0.1)
+    logits = np.asarray([0.1, 0.0, -30.0, -30.0])
+    p0 = float(jax.nn.softmax(jnp.asarray(logits, jnp.float32))[0])
+    rivals = 0
+    for seed in range(16):
+        tok, margin = _margin(logits, temperature=1.0, top_p=0.52, seed=seed)
+        assert tok == 0
+        free, _ = _margin(logits, temperature=1.0, seed=seed)
+        if free == 1:
+            rivals += 1
+            np.testing.assert_allclose(margin, 4 * (p0 - 0.52), rtol=1e-3)
+        else:
+            np.testing.assert_allclose(margin, 0.1, rtol=1e-5)
+    assert 0 < rivals < 16
+    # greedy rows are never filtered: the plain top1-top2 gap
+    tok, margin = _margin(logits, temperature=0.0, top_p=0.52)
+    assert tok == 0
+    np.testing.assert_allclose(margin, 0.1, rtol=1e-5)
+
+
+def test_margin_counts_a_dropped_rival_below_the_cut():
+    # top_k=2 drops token 2, 0.03 below the lowest survivor; where its
+    # Gumbel draw would beat the winner, the margin is at most 0.03
+    logits = np.asarray([1.0, 0.5, 0.47, -30.0])
+    rivals = 0
+    for seed in range(32):
+        tok, margin = _margin(logits, temperature=1.0, top_k=2, seed=seed)
+        assert tok in (0, 1)
+        free, _ = _margin(logits, temperature=1.0, seed=seed)
+        if free == 2:
+            rivals += 1
+            assert margin <= 0.03 + 1e-5
+    assert rivals > 0
+
+
 def test_generate_sampled_reproducible_and_greedy_default():
     from repro.configs import get_smoke_config
     from repro.models import params as Pm

@@ -37,6 +37,7 @@ _SCRIPT = textwrap.dedent("""
     import numpy as np
 
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models import params as Pm
     from repro.serving import ContinuousBatcher, Request, SamplingParams
 
@@ -60,8 +61,8 @@ _SCRIPT = textwrap.dedent("""
                 for i in range(6)]
 
     MESHES = {"none": None,
-              "m11": jax.make_mesh((1, 1), ("data", "model")),
-              "m22": jax.make_mesh((2, 2), ("data", "model"))}
+              "m11": make_mesh((1, 1), ("data", "model")),
+              "m22": make_mesh((2, 2), ("data", "model"))}
 
     out = {}
     for layout in ("dense", "paged"):
@@ -169,12 +170,11 @@ def test_engine_ctors_keyword_only():
 
 
 def test_mesh_rejects_pallas():
-    import jax
-
+    from repro.launch.mesh import make_mesh
     from repro.serving import DenseEngine, PagedEngine
 
     cfg, params = _smoke()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="[Pp]allas"):
         DenseEngine(cfg, params, n_slots=2, capacity=32, use_pallas=True,
                     mesh=mesh)
@@ -186,12 +186,13 @@ def test_mesh_rejects_pallas():
 def test_mesh_rejects_indivisible_slots():
     import jax
 
+    from repro.launch.mesh import make_mesh
     from repro.serving import DenseEngine
 
     cfg, params = _smoke()
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 devices for a data axis > 1")
-    mesh = jax.make_mesh((2, 1), ("data", "model"))
+    mesh = make_mesh((2, 1), ("data", "model"))
     with pytest.raises(ValueError, match="slot group"):
         DenseEngine(cfg, params, n_slots=3, capacity=32, mesh=mesh)
 

@@ -200,146 +200,150 @@ def attention_block(p, x, cfg: ModelConfig, *, positions=None, cache=None,
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    q = q.reshape(B, S, H, hd)
-    k = k.reshape(B, S, KV, hd)
-    v = v.reshape(B, S, KV, hd)
-    if shard is not None:
-        q = shard.act(q, batch=0, heads=2)
-        k = shard.act(k, batch=0, heads=2)
-        v = shard.act(v, batch=0, heads=2)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    # the named scope "attn" (serving/telemetry.DEVICE_SCOPES) tags
+    # everything between the projections: rope, the cache row write
+    # and the cache read, by the XLA path or the Pallas kernel
+    with jax.named_scope("attn"):
+        q = q.reshape(B, S, H, hd)
+        k = k.reshape(B, S, KV, hd)
+        v = v.reshape(B, S, KV, hd)
+        if shard is not None:
+            q = shard.act(q, batch=0, heads=2)
+            k = shard.act(k, batch=0, heads=2)
+            v = shard.act(v, batch=0, heads=2)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.norm_eps)
 
-    if cache is None:
-        if positions is None:
-            positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-        if cfg.mrope:
-            pos3 = (positions if positions.ndim == 3 else
-                    jnp.broadcast_to(positions[..., None],
-                                     positions.shape + (3,)))
-            cos, sin = mrope_angles(pos3, hd, cfg.rope_theta,
-                                    cfg.mrope_sections)
-            pos_1d = pos3[..., 0]
-        else:
-            cos, sin = rope_angles(positions, hd, cfg.rope_theta)
-            pos_1d = positions
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        window = cfg.sliding_window
-        if use_pallas and not cfg.mrope and not cfg.chunked_attention:
-            from repro.kernels.flash_attention import ops as fa_ops
-
-            out = fa_ops.flash_attention(q, k, v, causal=True, window=window)
-        elif cfg.attention_impl == "chunked":
-            out = chunked_attention(q, k, v, pos_1d, pos_1d, cfg,
-                                    layer_chunked=layer_chunked)
-        else:
-            mask = _attn_mask(pos_1d, pos_1d, window, cfg.chunked_attention,
-                              chunk_on=layer_chunked)
-            out = multi_head_attention(q, k, v, mask)
-        new_cache = None
-    else:
-        # decode: append the S new tokens to the cache starting at
-        # cache["pos"] (scalar, or (B,) per-slot positions).  A multi-token
-        # block (chunked prefill) must not wrap the ring past entries its own
-        # earlier tokens still attend to — the serving engine caps block
-        # sizes so writes never evict live window entries.
-        pos = cache["pos"]
-        pos_b = jnp.broadcast_to(pos, (B,))
-        abs_pos = pos_b[:, None] + jnp.arange(S)[None, :]  # (B, S)
-        default_pos = positions is None
-        if default_pos:
-            positions = abs_pos
-        if cfg.mrope:
-            pos3 = (positions if positions.ndim == 3 else
-                    jnp.broadcast_to(positions[..., None],
-                                     positions.shape + (3,)))
-            cos, sin = mrope_angles(pos3, hd, cfg.rope_theta,
-                                    cfg.mrope_sections)
-        else:
-            cos, sin = rope_angles(positions, hd, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        paged = "block_table" in cache
-        kv_dtype = cache["k"].dtype  # may be narrower (kv_cache_dtype)
-        b_idx = jnp.arange(B)[:, None]
-        out = None
-        if paged:
-            # paged pool: the S new tokens land in the shared pool through
-            # the block table, then attention reads the pool back.  Two
-            # paths: the Pallas v2 kernel fuses the scatter INTO the same
-            # grid pass that streams page tiles through the block table
-            # (no separate pool scatter, no (B, T, KV, hd) gather); the
-            # XLA path scatters into the flat pool and gathers each lane's
-            # whole logical ring.  Unallocated table entries point at the
-            # null page 0; its (garbage) entries sit at ring indices past
-            # `last` and are cut by the validity mask either way.
-            bt = cache["block_table"]  # (B, P) page ids
-            psz = cache["k"].shape[2]
-            T = bt.shape[1] * psz
-            if (paged_kernel == "pallas" and shard is None
-                    and not cfg.mrope and not cfg.chunked_attention
-                    and positions.ndim == 2 and S <= T):
-                # eligible for the kernel: any S block (decode, chunked
-                # prefill, resume-recompute), default or per-row 1-D
-                # positions.  Still XLA-only: M-RoPE (3-D positions),
-                # chunked-local masking, mesh sharding (the kernel is a
-                # single-device program), S > ring.
-                from repro.kernels.paged_attention import ops as pa_ops
-
-                out, store_k, store_v = pa_ops.paged_attention_update(
-                    q, k, v, cache["k"], cache["v"], bt, abs_pos[:, -1],
-                    window=cfg.sliding_window,
-                    q_positions=None if default_pos else positions)
+        if cache is None:
+            if positions is None:
+                positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+            if cfg.mrope:
+                pos3 = (positions if positions.ndim == 3 else
+                        jnp.broadcast_to(positions[..., None],
+                                         positions.shape + (3,)))
+                cos, sin = mrope_angles(pos3, hd, cfg.rope_theta,
+                                        cfg.mrope_sections)
+                pos_1d = pos3[..., 0]
             else:
-                # pool (n_pages, KV, psz, hd): ring slot i of lane b is
-                # row i % psz of page bt[b, i // psz]
-                slots = abs_pos % T
-                page = bt[b_idx, slots // psz]  # (B, S)
-                store_k = cache["k"].at[page, :, slots % psz].set(
-                    k.astype(kv_dtype))
-                store_v = cache["v"].at[page, :, slots % psz].set(
-                    v.astype(kv_dtype))
-                if shard is not None:
-                    store_k = shard.act(store_k, heads=1)
-                    store_v = shard.act(store_v, heads=1)
-                ring = jnp.arange(T)
-                ring_page, ring_row = bt[:, ring // psz], ring % psz
-                ck = store_k[ring_page, :, ring_row]  # (B, T, KV, hd)
-                cv = store_v[ring_page, :, ring_row]
-                if shard is not None:
+                cos, sin = rope_angles(positions, hd, cfg.rope_theta)
+                pos_1d = positions
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            window = cfg.sliding_window
+            if use_pallas and not cfg.mrope and not cfg.chunked_attention:
+                from repro.kernels.flash_attention import ops as fa_ops
+
+                out = fa_ops.flash_attention(q, k, v, causal=True, window=window)
+            elif cfg.attention_impl == "chunked":
+                out = chunked_attention(q, k, v, pos_1d, pos_1d, cfg,
+                                        layer_chunked=layer_chunked)
+            else:
+                mask = _attn_mask(pos_1d, pos_1d, window, cfg.chunked_attention,
+                                  chunk_on=layer_chunked)
+                out = multi_head_attention(q, k, v, mask)
+            new_cache = None
+        else:
+            # decode: append the S new tokens to the cache starting at
+            # cache["pos"] (scalar, or (B,) per-slot positions).  A multi-token
+            # block (chunked prefill) must not wrap the ring past entries its own
+            # earlier tokens still attend to — the serving engine caps block
+            # sizes so writes never evict live window entries.
+            pos = cache["pos"]
+            pos_b = jnp.broadcast_to(pos, (B,))
+            abs_pos = pos_b[:, None] + jnp.arange(S)[None, :]  # (B, S)
+            default_pos = positions is None
+            if default_pos:
+                positions = abs_pos
+            if cfg.mrope:
+                pos3 = (positions if positions.ndim == 3 else
+                        jnp.broadcast_to(positions[..., None],
+                                         positions.shape + (3,)))
+                cos, sin = mrope_angles(pos3, hd, cfg.rope_theta,
+                                        cfg.mrope_sections)
+            else:
+                cos, sin = rope_angles(positions, hd, cfg.rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            paged = "block_table" in cache
+            kv_dtype = cache["k"].dtype  # may be narrower (kv_cache_dtype)
+            b_idx = jnp.arange(B)[:, None]
+            out = None
+            if paged:
+                # paged pool: the S new tokens land in the shared pool through
+                # the block table, then attention reads the pool back.  Two
+                # paths: the Pallas v2 kernel fuses the scatter INTO the same
+                # grid pass that streams page tiles through the block table
+                # (no separate pool scatter, no (B, T, KV, hd) gather); the
+                # XLA path scatters into the flat pool and gathers each lane's
+                # whole logical ring.  Unallocated table entries point at the
+                # null page 0; its (garbage) entries sit at ring indices past
+                # `last` and are cut by the validity mask either way.
+                bt = cache["block_table"]  # (B, P) page ids
+                psz = cache["k"].shape[2]
+                T = bt.shape[1] * psz
+                if (paged_kernel == "pallas" and shard is None
+                        and not cfg.mrope and not cfg.chunked_attention
+                        and positions.ndim == 2 and S <= T):
+                    # eligible for the kernel: any S block (decode, chunked
+                    # prefill, resume-recompute), default or per-row 1-D
+                    # positions.  Still XLA-only: M-RoPE (3-D positions),
+                    # chunked-local masking, mesh sharding (the kernel is a
+                    # single-device program), S > ring.
+                    from repro.kernels.paged_attention import ops as pa_ops
+
+                    out, store_k, store_v = pa_ops.paged_attention_update(
+                        q, k, v, cache["k"], cache["v"], bt, abs_pos[:, -1],
+                        window=cfg.sliding_window,
+                        q_positions=None if default_pos else positions)
+                else:
+                    # pool (n_pages, KV, psz, hd): ring slot i of lane b is
+                    # row i % psz of page bt[b, i // psz]
+                    slots = abs_pos % T
+                    page = bt[b_idx, slots // psz]  # (B, S)
+                    store_k = cache["k"].at[page, :, slots % psz].set(
+                        k.astype(kv_dtype))
+                    store_v = cache["v"].at[page, :, slots % psz].set(
+                        v.astype(kv_dtype))
+                    if shard is not None:
+                        store_k = shard.act(store_k, heads=1)
+                        store_v = shard.act(store_v, heads=1)
+                    ring = jnp.arange(T)
+                    ring_page, ring_row = bt[:, ring // psz], ring % psz
+                    ck = store_k[ring_page, :, ring_row]  # (B, T, KV, hd)
+                    cv = store_v[ring_page, :, ring_row]
+                    if shard is not None:
+                        ck = shard.act(ck, batch=0, heads=2)
+                        cv = shard.act(cv, batch=0, heads=2)
+            else:
+                T = cache["k"].shape[1]
+                slots = abs_pos % T  # ring writes; capacity == window when windowed
+                ck = cache["k"].at[b_idx, slots].set(k.astype(kv_dtype))
+                cv = cache["v"].at[b_idx, slots].set(v.astype(kv_dtype))
+                if shard is not None:  # ring: (B, T, KV, hd)
                     ck = shard.act(ck, batch=0, heads=2)
                     cv = shard.act(cv, batch=0, heads=2)
-        else:
-            T = cache["k"].shape[1]
-            slots = abs_pos % T  # ring writes; capacity == window when windowed
-            ck = cache["k"].at[b_idx, slots].set(k.astype(kv_dtype))
-            cv = cache["v"].at[b_idx, slots].set(v.astype(kv_dtype))
-            if shard is not None:  # ring: (B, T, KV, hd)
-                ck = shard.act(ck, batch=0, heads=2)
-                cv = shard.act(cv, batch=0, heads=2)
-            store_k, store_v = ck, cv
-        if out is None:
-            # absolute position held by ring slot i after the writes: the
-            # largest value congruent to i (mod T) that is <= the last
-            # written position.  For a non-ring cache (last < T) this
-            # reduces to k_pos = i for i <= last, invalid beyond.
-            last = abs_pos[:, -1]  # (B,)
-            idx = jnp.arange(T)
-            k_pos = last[:, None] - ((last[:, None] - idx[None, :]) % T)
-            valid = k_pos >= 0  # (B, T)
-            q_pos = positions[..., 0] if positions.ndim == 3 else positions
-            mask = _attn_mask(q_pos, k_pos, cfg.sliding_window,
-                              cfg.chunked_attention, chunk_on=layer_chunked)
-            mask &= valid[:, None, :]
-            out = multi_head_attention(q, ck.astype(q.dtype),
-                                       cv.astype(q.dtype), mask,
-                                       dtype=q.dtype)
-        new_cache = {"k": store_k, "v": store_v, "pos": pos + S}
+                store_k, store_v = ck, cv
+            if out is None:
+                # absolute position held by ring slot i after the writes: the
+                # largest value congruent to i (mod T) that is <= the last
+                # written position.  For a non-ring cache (last < T) this
+                # reduces to k_pos = i for i <= last, invalid beyond.
+                last = abs_pos[:, -1]  # (B,)
+                idx = jnp.arange(T)
+                k_pos = last[:, None] - ((last[:, None] - idx[None, :]) % T)
+                valid = k_pos >= 0  # (B, T)
+                q_pos = positions[..., 0] if positions.ndim == 3 else positions
+                mask = _attn_mask(q_pos, k_pos, cfg.sliding_window,
+                                  cfg.chunked_attention, chunk_on=layer_chunked)
+                mask &= valid[:, None, :]
+                out = multi_head_attention(q, ck.astype(q.dtype),
+                                           cv.astype(q.dtype), mask,
+                                           dtype=q.dtype)
+            new_cache = {"k": store_k, "v": store_v, "pos": pos + S}
 
-    if shard is not None:
-        out = shard.act(out, batch=0, heads=2)
+        if shard is not None:
+            out = shard.act(out, batch=0, heads=2)
     out = out.reshape(B, S, H * hd) @ p["wo"]
     return out, new_cache
 

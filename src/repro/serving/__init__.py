@@ -17,7 +17,8 @@ Observability contract: ``telemetry`` is the stack-wide substrate every
 layer reports through — a `Telemetry` sink carried on
 ``ServingConfig(telemetry=...)`` and shared by the batcher, its engine
 and its frontend (the router holds its own plus a `merged_telemetry()`
-view over the fleet).  Three facets:
+view over the fleet).  Four facets, all on one clock (`Telemetry.now`
+is `time.monotonic`, the asyncio loop's clock):
 
 - **metrics registry** — `Counter` / `Gauge` / `Histogram` (fixed
   buckets, retained samples, p50/p95/p99, mergeable across replicas)
@@ -26,36 +27,51 @@ view over the fleet).  Three facets:
   ``requests_intake_total`` / ``requests_total{outcome=...}`` (every
   handle ends in exactly ONE outcome, so intake == sum over outcomes);
   the scheduler owns ``sched_preemptions_total{reason=...}``,
-  ``engine_cow_copies_total``, ``pool_page_growths_total``,
-  ``pool_pages_in_use`` and ``engine_disp_per_tick``; the router owns
+  ``engine_cow_copies_total``, ``pool_page_growths_total`` and
+  ``pool_pages_in_use``; the router owns
   ``router_migrations_total`` / ``router_failovers_total`` and the
   per-link byte ledger ``router_recipe_bytes_total{link="src->dst"}``
-  / ``router_kv_page_bytes_total``.
+  / ``router_kv_page_bytes_total``.  The fused tick's one-dispatch
+  rule reads off the batcher's own counters
+  (``decode_dispatches / decode_ticks``).
 - **request-lifecycle tracer** — every rid carries a span log of
   timestamped transitions: intake -> queued -> (resume ->) prefill ->
   decode <-> preempt{reason} -> migrate_out / migrate_in -> exactly one
   terminal event (finished / cancelled / expired / failed /
   migrate_out).  The frontend and scheduler dedupe terminal events
   through `Telemetry.last_event`; per-tick engine spans
-  (`Telemetry.tick`) record dispatch wall time with CoW / page-growth /
+  (`Telemetry.tick`) record dispatch wall time, the seconds the tick
+  waited on the device (``wait_s``), and CoW / page-growth /
   preemption annotations.  A migrated request's spans live on BOTH
   replicas' sinks and interleave by timestamp under
   `Telemetry.merged`.
+- **host spans and device scopes** — `Telemetry.span(name)` opens a
+  `jax.profiler.TraceAnnotation` when ``Telemetry(profile=True)`` and
+  returns the shared no-op `NULL_SPAN` otherwise.  The names are
+  `HOST_SPANS`: ``frontend.intake`` / ``frontend.pump`` (the frontend's
+  loop turn), ``sched.admit`` / ``sched.pages`` / ``sched.inputs`` /
+  ``sched.commit`` (the tick's phases), the engines' dispatch spans
+  (``paged.decode``, ``paged.prefill``, ``dense.*``,
+  ``per_slot.step``) and ``engine.wait`` (a blocking result fetch).
+  Inside the compiled step programs the three `DEVICE_SCOPES`
+  (``attn``, ``kv_pool``, ``sample``) are `jax.named_scope`s: metadata
+  on every op, so a device trace splits a step by layer, while the ops
+  themselves are unchanged.
 - **exporters** — `Telemetry.snapshot()` (nested dict; both `stats()`
-  methods are compatibility views over it), Chrome/Perfetto
+  methods are compatibility views over it) and Chrome/Perfetto
   trace_event JSON (`perfetto_trace` / `write_trace`,
   ``--trace out.json`` on launch/serve.py: one process track per
-  replica, engine ticks on thread 0, one thread per request), and an
-  optional `jax.profiler` annotation around the jitted steps
-  (``Telemetry(profile=True)``).
+  replica, engine ticks on thread 0, one thread per request).
 
 Zero-overhead rule: ``telemetry=None`` (the default) must add NOTHING
 to the hot path — every scheduler/engine call site guards with a plain
-``is not None`` check, recording is host-side only, and the fused tick
-stays at exactly 1.00 dispatch whether or not a sink is attached (the
+``is not None`` check (a span site falls to the shared `NULL_SPAN`),
+recording is host-side only, and the fused tick stays at exactly 1.00
+dispatch whether or not a sink is attached (the
 ``serving_telemetry_overhead`` bench row gates overhead <= 5% in CI).
 The frontend keeps a private sink when the config carries none — it
-records only at request-lifecycle boundaries, never per tick.
+records only at request-lifecycle boundaries, never per tick, and
+opens spans only through the stack's own sink.
 Placement feedback closes the loop: the router's `_score` demotes
 replicas whose ``serving_ttft_ms`` p95 trails the fleet's best.
 
@@ -262,6 +278,9 @@ from repro.serving.router import (  # noqa: F401
     RouterHandle,
 )
 from repro.serving.telemetry import (  # noqa: F401
+    DEVICE_SCOPES,
+    HOST_SPANS,
+    NULL_SPAN,
     TERMINAL_EVENTS,
     Counter,
     Gauge,

@@ -37,8 +37,6 @@ MESH tick, with slots sharded over the data axes and heads over "model".
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,10 +53,7 @@ from repro.serving.serve_step import (make_engine_step,
                                       make_paged_prefill_step,
                                       make_slot_prefill_step)
 from repro.serving.sharding import as_plan, tree_device_nbytes
-
-# shared no-op context for the telemetry=None fast path: the annotate
-# wrapper costs one `is not None` check and zero allocations per dispatch
-_NULL = contextlib.nullcontext()
+from repro.serving.telemetry import NULL_SPAN
 
 
 def _check_mesh_kernel(plan, use_pallas: bool, kernel: str = "xla"):
@@ -160,17 +155,18 @@ class DenseEngine:
         """Write a (1, S) prompt block into slot s's lanes in one call;
         returns (token, margin, logprob) sampled from the block's last
         position."""
-        with (self.telemetry.annotate("dense.prefill")
-              if self.telemetry is not None else _NULL):
+        tel = self.telemetry
+        with (tel.span("dense.prefill") if tel is not None else NULL_SPAN):
             tok, margin, logprob, self.cache = self._prefill(
                 self.params, self.cache, s, jnp.asarray(block), reset, row)
         self.prefill_dispatches += 1
-        return int(tok), float(margin), float(logprob)
+        with (tel.waiting() if tel is not None else NULL_SPAN):
+            return int(tok), float(margin), float(logprob)
 
     def decode(self, toks, active_mask, sampling: SlotSampling):
         """One fused tick: every slot advances one token in ONE dispatch."""
-        with (self.telemetry.annotate("dense.decode")
-              if self.telemetry is not None else _NULL):
+        tel = self.telemetry
+        with (tel.span("dense.decode") if tel is not None else NULL_SPAN):
             # jnp.array, not jnp.asarray: the mask is engine state rewritten
             # below while the dispatch may still run, and on the CPU
             # backend jnp.asarray can alias a numpy buffer instead of
@@ -181,7 +177,8 @@ class DenseEngine:
                 sampling)
         self.decode_dispatches += 1
         self._reset_mask[:] = False
-        return np.asarray(nxt), np.asarray(margins), np.asarray(logps)
+        with (tel.waiting() if tel is not None else NULL_SPAN):
+            return np.asarray(nxt), np.asarray(margins), np.asarray(logps)
 
     def cache_nbytes(self) -> int:
         """GLOBAL decode-state bytes, summed across every device."""
@@ -327,18 +324,19 @@ class PagedEngine:
 
     def prefill_block(self, s: int, block, off: int, reset: bool,
                       row: SlotSampling):
-        with (self.telemetry.annotate("paged.prefill")
-              if self.telemetry is not None else _NULL):
+        tel = self.telemetry
+        with (tel.span("paged.prefill") if tel is not None else NULL_SPAN):
             tok, margin, logprob, self.cache = self._prefill(
                 self.params, self.cache, s, jnp.asarray(block),
                 np.int32(off), jnp.asarray(self.block_table[s:s + 1]),
                 reset, row)
         self.prefill_dispatches += 1
-        return int(tok), float(margin), float(logprob)
+        with (tel.waiting() if tel is not None else NULL_SPAN):
+            return int(tok), float(margin), float(logprob)
 
     def decode(self, toks, active_mask, sampling: SlotSampling):
-        with (self.telemetry.annotate("paged.decode")
-              if self.telemetry is not None else _NULL):
+        tel = self.telemetry
+        with (tel.span("paged.decode") if tel is not None else NULL_SPAN):
             # copies of the engine's host state (see DenseEngine.decode):
             # positions and masks are rewritten below, before the
             # dispatch's results are read
@@ -352,7 +350,8 @@ class PagedEngine:
         self._copy_src[:] = 0
         self._copy_dst[:] = 0
         self.slot_pos[active_mask] += 1  # idle lanes stay pinned
-        return np.asarray(nxt), np.asarray(margins), np.asarray(logps)
+        with (tel.waiting() if tel is not None else NULL_SPAN):
+            return np.asarray(nxt), np.asarray(margins), np.asarray(logps)
 
     def cache_nbytes(self) -> int:
         """GLOBAL decode-state bytes (every device summed), host block
@@ -407,13 +406,14 @@ class PerSlotEngine:
 
     def step(self, s: int, tok: int, row: SlotSampling):
         """Advance one slot by one token (its own batch-1 dispatch)."""
-        with (self.telemetry.annotate("per_slot.step")
-              if self.telemetry is not None else _NULL):
+        tel = self.telemetry
+        with (tel.span("per_slot.step") if tel is not None else NULL_SPAN):
             t, m, lp, self.caches[s] = self._step(
                 self.params, self.caches[s],
                 jnp.asarray([[tok]], jnp.int32), row)
         self.decode_dispatches += 1
-        return int(t), float(m), float(lp)
+        with (tel.waiting() if tel is not None else NULL_SPAN):
+            return int(t), float(m), float(lp)
 
     def cache_nbytes(self) -> int:
         """Live device bytes of this engine's decode state."""

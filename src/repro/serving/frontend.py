@@ -59,7 +59,10 @@ Lifecycle semantics:
   / expired / failed / migrated), so intake == sum of outcomes — and
   the request-lifecycle span events it owns: "intake", "migrate_in" /
   "migrate_out" (the router boundary) and the terminal event, deduped
-  against the batcher side via `Telemetry.last_event`.
+  against the batcher side via `Telemetry.last_event`.  With a
+  profiling sink on the stack, each loop turn opens the host spans
+  ``frontend.intake`` (cancels, deadline expiry, intake drain) and
+  ``frontend.pump`` (streaming the tick's tokens).
 
 Invalid requests (empty prompt, prompt >= capacity, infeasible page
 budget, ...) fail their OWN handle — `result()` re-raises the
@@ -72,8 +75,8 @@ import asyncio
 from repro.serving.sampling import SamplingParams
 from repro.serving.scheduler import (Completion, DeadlineExpired,
                                      RecomputeRecipe, Request)
-from repro.serving.telemetry import (TERMINAL_EVENTS, Telemetry,
-                                     percentile)
+from repro.serving.telemetry import (NULL_SPAN, TERMINAL_EVENTS,
+                                     Telemetry, percentile)
 
 _END = object()  # stream terminator sentinel
 
@@ -211,6 +214,9 @@ class ServingFrontend:
         # boundaries (intake, first token, terminal outcome), never per
         # tick, so a private sink costs nothing on the engine hot path
         self.telemetry = getattr(batcher, "telemetry", None) or Telemetry()
+        # the stack's own sink (None without one) opens the loop's host
+        # spans; a private sink never profiles
+        self._spans = getattr(batcher, "telemetry", None)
 
     # ---------------------------------------------------------- lifecycle
 
@@ -492,11 +498,14 @@ class ServingFrontend:
         return bool(b.queue) or any(r is not None for r in b.slot_req)
 
     async def _run(self):
+        tel = self._spans
         try:
             while True:
-                self._apply_cancels()
-                self._expire_deadlines()
-                self._drain()
+                with (tel.span("frontend.intake") if tel is not None
+                      else NULL_SPAN):
+                    self._apply_cancels()
+                    self._expire_deadlines()
+                    self._drain()
                 if not self._busy():
                     # idle: park until the next submission arrives
                     handle = await self._intake.get()
@@ -506,8 +515,10 @@ class ServingFrontend:
                     await asyncio.to_thread(self.batcher.step)
                 else:
                     self.batcher.step()
-                self._apply_cancels()  # cancels raced the tick: drop now
-                self._pump()
+                with (tel.span("frontend.pump") if tel is not None
+                      else NULL_SPAN):
+                    self._apply_cancels()  # cancels raced the tick
+                    self._pump()
                 # one tick per loop turn: let consumers interleave
                 await asyncio.sleep(0)
         except asyncio.CancelledError:

@@ -107,7 +107,7 @@ from repro.serving.config import ServingConfig
 from repro.serving.engine import DenseEngine, PagedEngine, PerSlotEngine
 from repro.serving.sampling import (GREEDY, SamplingParams, SlotSampling,
                                     branch_key, key_zeros)
-from repro.serving.telemetry import TERMINAL_EVENTS
+from repro.serving.telemetry import NULL_SPAN, TERMINAL_EVENTS
 
 
 class DeadlineExpired(Exception):
@@ -616,14 +616,15 @@ class _BatcherBase:
 
     def step(self):
         """One engine tick.  With a telemetry sink attached, the tick is
-        timed and annotated (active slots, dispatches, CoW copies, page
-        growths, preemptions) and the dispatch-rate / pool gauges are
-        refreshed; ``telemetry=None`` falls straight through to the
-        layout-specific `_step_inner` — zero per-tick overhead."""
+        timed and annotated (seconds waiting on the device, active slots,
+        dispatches, CoW copies, page growths, preemptions) and the pool
+        gauge is refreshed; ``telemetry=None`` falls straight through to
+        the layout-specific `_step_inner` — zero per-tick overhead."""
         tel = self.telemetry
         if tel is None:
             return self._step_inner()
         t0 = tel.now()
+        w0 = tel.waited_s
         d0 = self.engine.decode_dispatches + self.engine.prefill_dispatches
         a0 = self.decode_active_slots
         c0 = getattr(self, "cow_copies", 0)
@@ -632,14 +633,13 @@ class _BatcherBase:
         out = self._step_inner()
         tel.tick(
             t0, tel.now() - t0,
+            wait_s=tel.waited_s - w0,
             active=self.decode_active_slots - a0,
             dispatches=self.engine.decode_dispatches
             + self.engine.prefill_dispatches - d0,
             cow_copies=getattr(self, "cow_copies", 0) - c0,
             page_growths=getattr(self, "page_growths", 0) - g0,
             preemptions=self.preemptions - p0)
-        tel.gauge("engine_disp_per_tick").set(
-            self.decode_dispatches / max(1, self.decode_ticks))
         alloc = getattr(self, "allocator", None)
         if alloc is not None:
             tel.gauge("pool_pages_in_use").set(alloc.in_use)
@@ -1363,46 +1363,56 @@ class ContinuousBatcher(_BatcherBase):
         per the slot's SamplingParams).  The tick first secures private
         ownership of each live slot's write page — lazy growth and
         copy-on-write reclaim, preempting on exhaustion — still exactly
-        one device dispatch."""
-        self._fill_slots()
-        self._secure_slot_pages()
+        one device dispatch.  With a profiling sink, each phase runs
+        under its host span: admission and prefill (``sched.admit``),
+        page securing (``sched.pages``), the dispatch's inputs
+        (``sched.inputs``) and the bookkeeping after it
+        (``sched.commit``)."""
+        tel = self.telemetry
+        with (tel.span("sched.admit") if tel is not None else NULL_SPAN):
+            self._fill_slots()
+        with (tel.span("sched.pages") if tel is not None else NULL_SPAN):
+            self._secure_slot_pages()
         active = [s for s in range(self.n_slots)
                   if self.slot_req[s] is not None]
         if not active:
             return False
-        toks = np.zeros((self.n_slots, 1), np.int32)
-        emit = np.zeros((self.n_slots,), bool)
-        for s in active:
-            req, st = self.slot_req[s], self.slot_state[s]
-            p = len(req.prompt)
-            if st["fed"] < p:
-                toks[s, 0] = req.prompt[st["fed"]]
-            else:
-                toks[s, 0] = st["emitted"][st["fed"] - p]
-            # this feed produces a NEW token only when it is the last
-            # known one; earlier feeds are prompt tokens or a resume
-            # replay, whose outputs are already known and discarded
-            emit[s] = st["fed"] == p + len(st["emitted"]) - 1
-        active_mask = np.zeros((self.n_slots,), bool)
-        active_mask[active] = True
+        with (tel.span("sched.inputs") if tel is not None else NULL_SPAN):
+            toks = np.zeros((self.n_slots, 1), np.int32)
+            emit = np.zeros((self.n_slots,), bool)
+            for s in active:
+                req, st = self.slot_req[s], self.slot_state[s]
+                p = len(req.prompt)
+                if st["fed"] < p:
+                    toks[s, 0] = req.prompt[st["fed"]]
+                else:
+                    toks[s, 0] = st["emitted"][st["fed"] - p]
+                # this feed produces a NEW token only when it is the last
+                # known one; earlier feeds are prompt tokens or a resume
+                # replay, whose outputs are already known and discarded
+                emit[s] = st["fed"] == p + len(st["emitted"]) - 1
+            active_mask = np.zeros((self.n_slots,), bool)
+            active_mask[active] = True
+            sampling = self._sampling_batch()
         nxt, margins, logps = self.engine.decode(toks, active_mask,
-                                                 self._sampling_batch())
-        self.decode_ticks += 1
-        self.decode_active_slots += len(active)
-        spg = max(1, self.n_slots // self.n_slot_groups)
-        for s in active:
-            self.group_active[s // spg] += 1
-        self.active_slot_steps += len(active)
-        self.total_slot_steps += self.n_slots
-        for s in active:
-            st = self.slot_state[s]
-            st["fed"] += 1
-            st["ran"] += 1
-            if emit[s]:
-                st["emitted"].append(int(nxt[s]))
-                st["margins"].append(float(margins[s]))
-                st["logps"].append(float(logps[s]))
-                self._finish_if_done(s)
+                                                 sampling)
+        with (tel.span("sched.commit") if tel is not None else NULL_SPAN):
+            self.decode_ticks += 1
+            self.decode_active_slots += len(active)
+            spg = max(1, self.n_slots // self.n_slot_groups)
+            for s in active:
+                self.group_active[s // spg] += 1
+            self.active_slot_steps += len(active)
+            self.total_slot_steps += self.n_slots
+            for s in active:
+                st = self.slot_state[s]
+                st["fed"] += 1
+                st["ran"] += 1
+                if emit[s]:
+                    st["emitted"].append(int(nxt[s]))
+                    st["margins"].append(float(margins[s]))
+                    st["logps"].append(float(logps[s]))
+                    self._finish_if_done(s)
         return True
 
 

@@ -8,7 +8,14 @@ Every fused step takes a ``SlotSampling`` batch (per-slot PRNG keys, emit
 indices, temperature / top-k / top-p — see serving/sampling.py): sampled
 and greedy slots ride through the SAME compiled program, so stochastic
 decode still costs exactly one dispatch per engine tick and a temperature
-of 0 recovers the greedy trajectory bit-for-bit."""
+of 0 recovers the greedy trajectory bit-for-bit.
+
+The fused steps tag their device work with two of the named scopes in
+serving/telemetry.DEVICE_SCOPES (metadata only: the compiled ops are
+the same): ``kv_pool`` around the step-level pool work (slot resets,
+copy-on-write page copies, a prefill's slot slice and update) and
+``sample`` around sampling, argmax and logprob; the model's attention
+carries ``attn`` (models/layers.attention_block)."""
 from __future__ import annotations
 
 import jax
@@ -83,7 +90,8 @@ def make_engine_step(cfg: ModelConfig, use_pallas: bool = False,
     mesh, so mesh=(1,1) compiles the same program as plan=None)."""
 
     def step(params, cache, tokens, reset_mask, active_mask, sampling):
-        cache = reset_slots(cfg, cache, reset_mask)
+        with jax.named_scope("kv_pool"):
+            cache = reset_slots(cfg, cache, reset_mask)
         if plan is not None:
             cache = plan.constrain_dense_cache(cache)
         pos0 = cache["pos"]
@@ -94,11 +102,12 @@ def make_engine_step(cfg: ModelConfig, use_pallas: bool = False,
             # replicate the Gumbel-max region: sharding the legacy threefry
             # RNG would change the noise bits (see ShardingPlan.rep)
             logits = plan.rep(logits)
-        scores, cut = batched_sample(logits, sampling)
-        if plan is not None:
-            scores = plan.rep(scores)
-        next_tok, margin = argmax_with_margin(scores, cut)
-        logprob = token_logprob(logits, next_tok)
+        with jax.named_scope("sample"):
+            scores, cut = batched_sample(logits, sampling)
+            if plan is not None:
+                scores = plan.rep(scores)
+            next_tok, margin = argmax_with_margin(scores, cut)
+            logprob = token_logprob(logits, next_tok)
         new_cache = dict(out.cache,
                          pos=jnp.where(active_mask, out.cache["pos"], pos0))
         return next_tok, margin, logprob, new_cache
@@ -142,8 +151,9 @@ def make_paged_engine_step(cfg: ModelConfig, use_pallas: bool = False,
 
     def step(params, cache, tokens, pos, block_table, reset_mask,
              copy_src, copy_dst, sampling):
-        cache = reset_paged_slots(cfg, cache, reset_mask)
-        cache = cow_copy_pages(cfg, cache, copy_src, copy_dst)
+        with jax.named_scope("kv_pool"):
+            cache = reset_paged_slots(cfg, cache, reset_mask)
+            cache = cow_copy_pages(cfg, cache, copy_src, copy_dst)
         if plan is not None:
             cache = plan.constrain_paged_cache(cache)
         full = dict(cache, pos=pos, block_table=block_table)
@@ -153,11 +163,12 @@ def make_paged_engine_step(cfg: ModelConfig, use_pallas: bool = False,
         logits = out.logits[:, -1]
         if plan is not None:
             logits = plan.rep(logits)
-        scores, cut = batched_sample(logits, sampling)
-        if plan is not None:
-            scores = plan.rep(scores)
-        next_tok, margin = argmax_with_margin(scores, cut)
-        logprob = token_logprob(logits, next_tok)
+        with jax.named_scope("sample"):
+            scores, cut = batched_sample(logits, sampling)
+            if plan is not None:
+                scores = plan.rep(scores)
+            next_tok, margin = argmax_with_margin(scores, cut)
+            logprob = token_logprob(logits, next_tok)
         new_cache = {k: v for k, v in out.cache.items() if k != "pos"}
         return next_tok, margin, logprob, new_cache
 
@@ -180,22 +191,25 @@ def make_slot_prefill_step(cfg: ModelConfig, use_pallas: bool = False,
     the block ends the prompt, margin its top1-top2 score gap."""
 
     def step(params, cache, slot, tokens, reset, row):
-        sub = slot_slice(cfg, cache, slot)
-        sub = jax.tree.map(
-            lambda a: jnp.where(reset, jnp.zeros((), a.dtype), a), sub)
+        with jax.named_scope("kv_pool"):
+            sub = slot_slice(cfg, cache, slot)
+            sub = jax.tree.map(
+                lambda a: jnp.where(reset, jnp.zeros((), a.dtype), a), sub)
         out = T.forward(params, cfg, tokens, cache=sub,
                         use_pallas=use_pallas, shard=plan)
-        cache = slot_update(cfg, cache, slot, out.cache)
+        with jax.named_scope("kv_pool"):
+            cache = slot_update(cfg, cache, slot, out.cache)
         if plan is not None:
             cache = plan.constrain_dense_cache(cache)
         logits = out.logits[0, -1]
         if plan is not None:
             logits = plan.rep(logits)
-        scores, cut = row_sample(logits, row)
-        if plan is not None:
-            scores = plan.rep(scores)
-        tok, margin = argmax_with_margin(scores[None], cut[None])
-        logprob = token_logprob(logits[None], tok)
+        with jax.named_scope("sample"):
+            scores, cut = row_sample(logits, row)
+            if plan is not None:
+                scores = plan.rep(scores)
+            tok, margin = argmax_with_margin(scores[None], cut[None])
+            logprob = token_logprob(logits[None], tok)
         return tok[0], margin[0], logprob[0], cache
 
     return step
@@ -221,24 +235,27 @@ def make_paged_prefill_step(cfg: ModelConfig, use_pallas: bool = False,
     make_slot_prefill_step."""
 
     def step(params, cache, slot, tokens, pos0, bt_row, reset, row):
-        sub = paged_slot_slice(cfg, cache, slot)
-        sub = reset_paged_sub(cfg, sub, reset)
+        with jax.named_scope("kv_pool"):
+            sub = paged_slot_slice(cfg, cache, slot)
+            sub = reset_paged_sub(cfg, sub, reset)
         full = dict(sub, pos=pos0, block_table=bt_row)
         out = T.forward(params, cfg, tokens, cache=full,
                         use_pallas=use_pallas, paged_kernel=kernel,
                         shard=plan)
         new = {k: v for k, v in out.cache.items() if k != "pos"}
-        cache = paged_slot_update(cfg, cache, slot, new)
+        with jax.named_scope("kv_pool"):
+            cache = paged_slot_update(cfg, cache, slot, new)
         if plan is not None:
             cache = plan.constrain_paged_cache(cache)
         logits = out.logits[0, -1]
         if plan is not None:
             logits = plan.rep(logits)
-        scores, cut = row_sample(logits, row)
-        if plan is not None:
-            scores = plan.rep(scores)
-        tok, margin = argmax_with_margin(scores[None], cut[None])
-        logprob = token_logprob(logits[None], tok)
+        with jax.named_scope("sample"):
+            scores, cut = row_sample(logits, row)
+            if plan is not None:
+                scores = plan.rep(scores)
+            tok, margin = argmax_with_margin(scores[None], cut[None])
+            logprob = token_logprob(logits[None], tok)
         return tok[0], margin[0], logprob[0], cache
 
     return step

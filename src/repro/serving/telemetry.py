@@ -1,7 +1,7 @@
 """Unified telemetry substrate for the four-layer serving stack.
 
 One `Telemetry` object per serving stack (threaded through
-`ServingConfig.telemetry`) owns three things:
+`ServingConfig.telemetry`) owns four things:
 
 - a **metrics registry** — named `Counter` / `Gauge` / `Histogram`
   series created on first use (`tel.counter(name)`, ...).  Counters and
@@ -10,14 +10,29 @@ One `Telemetry` object per serving stack (threaded through
   are exact and two replicas' histograms MERGE without loss
   (`Telemetry.merged` — the router's fleet aggregation).
 - a **request-lifecycle tracer** — `trace(rid, event, **attrs)` appends
-  a wall-clock-stamped state transition to the request's span log.  The
+  a timestamped state transition to the request's span log.  The
   event vocabulary: ``intake`` (frontend accepted the submission),
   ``queued`` (scheduler intake), ``resume``/``prefill``/``decode``
   (slot placement), ``preempt`` (with a ``reason`` attr), ``migrate_out``
   / ``migrate_in`` (router recipe shipping), and the terminals
   ``finished`` / ``cancelled`` / ``expired`` / ``failed``.  Engine ticks
   are recorded separately (`tick(t0, dur, **attrs)`) with dispatch wall
-  time and CoW / page-growth annotations.
+  time, the seconds spent waiting on the device (``wait_s``) and CoW /
+  page-growth annotations.
+- **host spans** — `span(name)` names a stretch of host work with a
+  `jax.profiler.TraceAnnotation` when ``Telemetry(profile=True)``, and
+  returns the shared no-op `NULL_SPAN` otherwise.  The vocabulary is
+  `HOST_SPANS`: the frontend's loop turn (``frontend.intake``,
+  ``frontend.pump``), the scheduler's tick phases (``sched.admit``,
+  ``sched.pages``, ``sched.inputs``, ``sched.commit``), the engines'
+  dispatches (``paged.decode``, ...) and their blocking result fetches
+  (``engine.wait``, whose seconds also feed the tick's ``wait_s``).
+  Inside the compiled step programs the matching names are
+  `DEVICE_SCOPES`, `jax.named_scope`s that tag every op's metadata:
+  ``attn`` (models/layers.attention_block, from the projected q/k/v to
+  the attention output: rope, the pool row write, the pool read),
+  ``kv_pool`` (the steps' slot resets, copy-on-write page copies and
+  slot slices / updates) and ``sample`` (sampling, argmax and logprob).
 - **exporters** — `snapshot()` (one nested dict: counters, gauges,
   histogram percentiles, span/tick totals; the layer `stats()` methods
   are compatibility views over it) and `perfetto_trace()` /
@@ -25,19 +40,20 @@ One `Telemetry` object per serving stack (threaded through
   per replica, one thread per request plus an engine-tick track, so a
   router failover drill is visually inspectable in ui.perfetto.dev).
 
+One clock: every timestamp here is `time.monotonic` (`Telemetry.now`),
+the clock of the asyncio loop that drives the frontend, so lifecycle
+events, ticks and a client's own records compare directly.
+
 Naming convention for series: ``<layer>_<what>[_<unit>|_total]`` —
 ``serving_ttft_ms``, ``sched_preemptions_total{reason=...}``,
 ``router_recipe_bytes_total{link="0->1"}``, ``engine_cow_copies_total``,
-``pool_pages_in_use``, ``engine_disp_per_tick``.
+``pool_pages_in_use``.
 
 Zero-overhead rule: every recording call on the engine/scheduler hot
 path is guarded by ``if telemetry is not None`` AT THE CALL SITE, so a
 stack built with ``telemetry=None`` (the default) allocates nothing per
 tick and dispatches nothing extra — recording is host-side only either
 way, and the fused tick stays at 1.00 dispatch/tick with telemetry on.
-`annotate(name)` optionally wraps the jitted steps in
-`jax.profiler.TraceAnnotation` (``Telemetry(profile=True)``); off, it
-returns a shared no-op context.
 """
 from __future__ import annotations
 
@@ -53,9 +69,21 @@ import numpy as np
 DEFAULT_BUCKETS = (0.1, 0.2, 0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0,
                    100.0, 200.0, 500.0, 1000.0, 2000.0, 5000.0)
 
-# shared no-op context: annotate() with profiling off returns this one
-# object, so the hot path never constructs a context manager per call
-_NULL_CONTEXT = contextlib.nullcontext()
+# shared no-op context: span() with profiling off returns this one
+# object, and call sites without a sink use it too, so the hot path
+# never constructs a context manager per call
+NULL_SPAN = contextlib.nullcontext()
+
+# every host span a stack opens (Telemetry.span), frontend to engine:
+# the frontend's loop turn, the tick's phases, the engines' dispatches
+# (one per jitted step call) and their blocking result fetches
+HOST_SPANS = ("frontend.intake", "frontend.pump", "sched.admit",
+              "sched.pages", "sched.inputs", "sched.commit",
+              "paged.decode", "paged.prefill", "dense.decode",
+              "dense.prefill", "per_slot.step", "engine.wait")
+# the jax.named_scope names inside the compiled step programs: the
+# attention of each layer, the step-level KV-pool work, the sampler
+DEVICE_SCOPES = ("attn", "kv_pool", "sample")
 
 
 def percentile(samples, q: float):
@@ -194,7 +222,11 @@ class Telemetry:
     passing it as ``ServingConfig(telemetry=...)`` — the batcher, its
     engine and the frontend all record into it, so `snapshot()` and the
     Perfetto export see the whole replica.  ``profile=True`` additionally
-    wraps the jitted engine steps in `jax.profiler.TraceAnnotation`."""
+    opens the host spans (`span`) as `jax.profiler.TraceAnnotation`s.
+
+    Clock: `now` is `time.monotonic`, the asyncio loop's clock, so a
+    lifecycle event or tick maps onto a client's records, and onto a
+    profiler trace through one anchor span stamped on the same clock."""
 
     def __init__(self, profile: bool = False):
         self.profile = profile
@@ -205,10 +237,12 @@ class Telemetry:
         self.spans: dict = {}
         # [(t0, dur, attrs), ...] — one entry per engine tick
         self.ticks: list = []
+        # seconds spent in engine.wait so far (a tick books its share)
+        self.waited_s = 0.0
 
     # ------------------------------------------------------------ registry
 
-    now = staticmethod(time.perf_counter)
+    now = staticmethod(time.monotonic)
 
     def counter(self, name: str) -> Counter:
         c = self.counters.get(name)
@@ -233,7 +267,7 @@ class Telemetry:
     def trace(self, rid: int, event: str, t: float | None = None, **attrs):
         """Record one lifecycle transition for request `rid`."""
         self.spans.setdefault(rid, []).append(
-            (time.perf_counter() if t is None else t, event, attrs))
+            (self.now() if t is None else t, event, attrs))
 
     def last_event(self, rid: int):
         ev = self.spans.get(rid)
@@ -241,16 +275,29 @@ class Telemetry:
 
     def tick(self, t0: float, dur: float, **attrs):
         """Record one engine tick (start + wall seconds + annotations:
-        active slots, dispatches, CoW copies, pages grown)."""
+        seconds waiting on the device, active slots, dispatches, CoW
+        copies, pages grown)."""
         self.ticks.append((t0, dur, attrs))
 
-    def annotate(self, name: str):
-        """Context manager for a jitted step: a `jax.profiler`
-        TraceAnnotation when profiling is on, else a shared no-op."""
+    def span(self, name: str):
+        """Context manager naming host work `name` (one of HOST_SPANS): a
+        `jax.profiler` TraceAnnotation when profiling is on, else the
+        shared no-op NULL_SPAN."""
         if not self.profile:
-            return _NULL_CONTEXT
+            return NULL_SPAN
         from jax import profiler
         return profiler.TraceAnnotation(name)
+
+    @contextlib.contextmanager
+    def waiting(self):
+        """The ``engine.wait`` span around a blocking fetch of a
+        dispatch's results; its seconds add to `waited_s`."""
+        t0 = self.now()
+        try:
+            with self.span("engine.wait"):
+                yield
+        finally:
+            self.waited_s += self.now() - t0
 
     # ----------------------------------------------------------- exporters
 

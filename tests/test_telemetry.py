@@ -4,6 +4,7 @@ scheduler / engine / frontend / router, Perfetto trace export, and the
 ``telemetry=None`` zero-overhead contract."""
 import asyncio
 import json
+import time
 import tracemalloc
 
 import jax
@@ -13,10 +14,11 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.models import params as Pm
 from repro.serving.config import ServingConfig
+from repro.serving.frontend import ServingFrontend
 from repro.serving.router import ReplicaRouter
 from repro.serving.scheduler import ContinuousBatcher, Request
-from repro.serving.telemetry import (TERMINAL_EVENTS, Histogram, Telemetry,
-                                     percentile, perfetto_trace,
+from repro.serving.telemetry import (HOST_SPANS, TERMINAL_EVENTS, Histogram,
+                                     Telemetry, percentile, perfetto_trace,
                                      write_trace)
 
 
@@ -98,7 +100,8 @@ def test_span_ordering_through_the_scheduler(setup):
         ts = [t for t, _, _ in evs]
         assert ts == sorted(ts)
     assert len(tel.ticks) == steps
-    assert tel.gauge("engine_disp_per_tick").value() <= 1.0
+    # the fused tick's one-dispatch rule, from the batcher's counters
+    assert eng.decode_dispatches == eng.decode_ticks
     snap = tel.snapshot()
     assert snap["requests_traced"] == len(reqs)
     assert snap["ticks"]["count"] == steps
@@ -251,3 +254,103 @@ def test_disabled_telemetry_is_free(setup):
     assert off_ticks == on_ticks
     assert off.decode_dispatches - d_off == on.decode_dispatches - d_on
     assert tel.snapshot()["span_events"] > 0  # the traced arm did record
+
+
+# ---------------------------------------- host spans, clock, wait time
+
+
+class _Raise:
+    def __init__(self, name):
+        raise AssertionError(f"span {name!r} opened without a sink")
+
+
+def test_untraced_tick_opens_no_span(setup, monkeypatch):
+    """A telemetry=None stack opens no host span: TraceAnnotation is
+    never constructed through a frontend-driven tick."""
+    cfg, params = setup
+    eng = ContinuousBatcher(cfg, params, ServingConfig(
+        n_slots=2, capacity=64, cache_layout="paged"))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Raise)
+
+    async def serve():
+        async with ServingFrontend(eng) as fe:
+            hs = [await fe.submit(r.prompt, r.max_new) for r in _reqs(cfg)]
+            return [await h.result() for h in hs]
+
+    assert len(asyncio.run(serve())) == 3
+    assert eng.decode_ticks > 0
+
+
+def test_profiled_stack_opens_declared_spans(setup, monkeypatch):
+    """With profiling on, every span a tick opens is declared in
+    HOST_SPANS, and the frontend, scheduler and engine each open theirs."""
+    cfg, params = setup
+    tel = Telemetry(profile=True)
+    eng = ContinuousBatcher(cfg, params, ServingConfig(
+        n_slots=2, capacity=64, cache_layout="paged", telemetry=tel))
+    opened = []
+
+    class Record:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Record)
+
+    async def serve():
+        async with ServingFrontend(eng) as fe:
+            hs = [await fe.submit(r.prompt, r.max_new) for r in _reqs(cfg)]
+            return [await h.result() for h in hs]
+
+    asyncio.run(serve())
+    assert set(opened) <= set(HOST_SPANS)
+    assert {"frontend.intake", "frontend.pump", "sched.admit",
+            "sched.pages", "sched.inputs", "sched.commit", "paged.decode",
+            "paged.prefill", "engine.wait"} <= set(opened)
+    # one engine.wait per dispatch: the result fetch after each
+    assert opened.count("engine.wait") == \
+        eng.decode_dispatches + eng.prefill_dispatches
+
+
+def test_timestamps_on_the_monotonic_clock(setup):
+    """Lifecycle events and ticks are stamped on time.monotonic, the
+    asyncio loop's clock: every stamp lies between readings of it
+    taken around the run."""
+    cfg, params = setup
+    assert Telemetry.now is time.monotonic
+    tel = Telemetry()
+    eng = ContinuousBatcher(cfg, params, ServingConfig(
+        n_slots=2, capacity=64, telemetry=tel))
+    before = time.monotonic()
+    eng.submit(_reqs(cfg))
+    eng.run()
+    after = time.monotonic()
+    stamps = [t for evs in tel.spans.values() for t, _, _ in evs]
+    stamps += [t0 for t0, _, _ in tel.ticks]
+    stamps += [t0 + d for t0, d, _ in tel.ticks]
+    assert len(stamps) > 3 * len(tel.ticks) // 2
+    assert all(before <= t <= after for t in stamps)
+
+
+def test_tick_records_its_wait(setup):
+    """Every tick books wait_s: the seconds spent fetching dispatch
+    results, within the tick's wall time and nonzero when it
+    dispatched; the ticks' waits add up to the sink's total."""
+    cfg, params = setup
+    tel = Telemetry()
+    eng = ContinuousBatcher(cfg, params, ServingConfig(
+        n_slots=2, capacity=64, telemetry=tel))
+    eng.submit(_reqs(cfg))
+    eng.run()
+    assert tel.ticks
+    for _, dur, attrs in tel.ticks:
+        assert 0.0 <= attrs["wait_s"] <= dur
+        if attrs["dispatches"]:
+            assert attrs["wait_s"] > 0.0
+    assert sum(a["wait_s"] for _, _, a in tel.ticks) == \
+        pytest.approx(tel.waited_s)

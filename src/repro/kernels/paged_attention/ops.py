@@ -14,7 +14,10 @@ models/layers.py must fail, not run the wrong path).  Rules:
   overwrite its own tokens — the serving engine never produces one; it
   must take the XLA path).
 - M-RoPE (3-D positions) and chunked-local attention masking are not
-  expressible in the kernel; models/layers.py keeps those on XLA.
+  expressible in the kernel, and it is a single-device program:
+  `pallas_eligible` says which models and placements take it, for
+  models/layers.py (which keeps the rest on XLA) and the serving engine
+  (which counts the pages it streams).
 """
 from __future__ import annotations
 
@@ -27,7 +30,40 @@ from repro.kernels import on_tpu
 from repro.kernels.paged_attention.paged_attention import \
     paged_attention_grouped
 
-DEFAULT_TILE_K = 4  # pages per MXU tile (page grid steps between dots)
+# pages per grid step, when the caller gives none: STEP_ROWS rows of K and
+# V a step (8 pages of 16 rows), with the four page buffers (K and V,
+# double-buffered) within STEP_VMEM_BYTES, and at most the ring's P.  A
+# grid step streams the live pages of one slot, every KV head at once,
+# and copies nothing past the slot's newest position.
+STEP_ROWS = 128
+STEP_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def default_tile_k(page_size: int, page_bytes: int, P: int) -> int:
+    """Pages per grid step for pages of `page_size` rows and `page_bytes`
+    (one page, every KV head, one of K or V) on a ring of P pages."""
+    return max(1, min(P, STEP_ROWS // page_size,
+                      STEP_VMEM_BYTES // (4 * page_bytes)))
+
+
+def pallas_eligible(cfg, shard=None) -> bool:
+    """Whether paged attention of model `cfg` can run the kernel: not with
+    M-RoPE's 3-D positions or chunked-local masking, and not on a mesh
+    (`shard`, a serving.sharding.ShardingPlan)."""
+    return shard is None and not (cfg.mrope or cfg.chunked_attention)
+
+
+def scatter_rows(pool, rows, block_table, last_pos):
+    """The XLA row write: rows (B, S, KV, hd) of positions last_pos - S + 1
+    .. last_pos into their block-table-addressed pool rows, in the pool's
+    dtype.  Pools narrower than 32 bits take it before the attention-only
+    kernel (a copy cannot address one row of a packed word)."""
+    S, psz = rows.shape[1], pool.shape[2]
+    T = block_table.shape[1] * psz
+    slots = (last_pos[:, None] - (S - 1)
+             + jnp.arange(S, dtype=jnp.int32)[None, :]) % T
+    page = jnp.take_along_axis(block_table, slots // psz, axis=1)
+    return pool.at[page, :, slots % psz].set(rows.astype(pool.dtype))
 
 
 def _validate(q, k_pool, v_pool, block_table, last_pos, q_positions):
@@ -74,6 +110,13 @@ def _dispatch(q, k_new, v_new, k_pool, v_pool, block_table, last_pos,
     P = block_table.shape[1]
     T = P * psz
 
+    if k_new is not None and jnp.dtype(k_pool.dtype).itemsize != 4:
+        k_pool = scatter_rows(k_pool, k_new, block_table, last_pos)
+        v_pool = scatter_rows(v_pool, v_new, block_table, last_pos)
+        k_new = v_new = None
+    if tile_k is None:
+        tile_k = default_tile_k(
+            psz, KV * psz * hd * jnp.dtype(k_pool.dtype).itemsize, P)
     tk = max(1, min(tile_k, P))
     pad = -P % tk
     if pad:
@@ -84,14 +127,17 @@ def _dispatch(q, k_new, v_new, k_pool, v_pool, block_table, last_pos,
 
     qg = q.reshape(B, S, KV, g, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, KV, S * g, hd)
-    if k_new is not None:
-        k_new = k_new.transpose(0, 2, 1, 3)  # (B, S, KV, hd) -> (B, KV, S, hd)
-        v_new = v_new.transpose(0, 2, 1, 3)
+    fused = k_new is not None
+    if fused:
+        # (B, S, KV, hd) -> (B, KV, S, hd), in the pool's dtype: the kernel
+        # copies these rows into the pool as they are
+        k_new = k_new.transpose(0, 2, 1, 3).astype(k_pool.dtype)
+        v_new = v_new.transpose(0, 2, 1, 3).astype(v_pool.dtype)
     res = paged_attention_grouped(
         qg, k_new, v_new, k_pool, v_pool, block_table, q_positions,
         last_pos, ring_len=T, window=window, tile_k=tk,
         interpret=not on_tpu())
-    out, kp, vp = res if k_new is not None else (res, k_pool, v_pool)
+    out, kp, vp = res if fused else (res, k_pool, v_pool)
     out = out.reshape(B, KV, S, g, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, S, H, hd)
     return out, kp, vp
@@ -99,7 +145,7 @@ def _dispatch(q, k_new, v_new, k_pool, v_pool, block_table, last_pos,
 
 @functools.partial(jax.jit, static_argnames=("window", "tile_k"))
 def paged_attention(q, k_pool, v_pool, block_table, last_pos, *,
-                    window: int = 0, tile_k: int = DEFAULT_TILE_K,
+                    window: int = 0, tile_k: int | None = None,
                     q_positions=None):
     """q: (B, S, H, hd) with H = g*KV (GQA) — an S-token query block per
     slot, already RoPE'd; its K/V must already be scattered into the pool
@@ -109,8 +155,9 @@ def paged_attention(q, k_pool, v_pool, block_table, last_pos, *,
     block_table: (B, P) int32 page ids; last_pos: (B,) int32 absolute
     position of the newest token per slot.  q_positions: optional (B, S)
     int32 per-row query positions (defaults to last_pos - S + 1 .. last_pos,
-    the contiguous decode block).  tile_k: pages accumulated per MXU tile.
-    Returns (B, S, H, hd)."""
+    the contiguous decode block).  tile_k: pages per grid step (None:
+    `default_tile_k` of the page's rows and bytes and P).  Returns
+    (B, S, H, hd)."""
     out, _, _ = _dispatch(q, None, None, k_pool, v_pool, block_table,
                           last_pos, window, tile_k, q_positions)
     return out
@@ -119,13 +166,15 @@ def paged_attention(q, k_pool, v_pool, block_table, last_pos, *,
 @functools.partial(jax.jit, static_argnames=("window", "tile_k"))
 def paged_attention_update(q, k_new, v_new, k_pool, v_pool, block_table,
                            last_pos, *, window: int = 0,
-                           tile_k: int = DEFAULT_TILE_K, q_positions=None):
+                           tile_k: int | None = None, q_positions=None):
     """Fused scatter + attention: the serving decode/prefill path.
 
     k_new/v_new: (B, S, KV, hd) just-projected K/V rows for positions
-    last_pos - S + 1 .. last_pos; the kernel writes them into their
-    block-table-addressed page rows (cast to the pool dtype) in the same
-    pass that reads the pool — no separate XLA pool scatter.  Returns
+    last_pos - S + 1 .. last_pos; the kernel copies them (cast to the
+    pool dtype) into their block-table-addressed page rows before it
+    reads the pool — no separate XLA pool scatter, and no other pool
+    byte written.  A pool narrower than 32 bits takes `scatter_rows`
+    and the attention-only kernel instead.  Returns
     (out, k_pool, v_pool): out (B, S, H, hd) plus the updated pools
     (aliased in-place onto the inputs)."""
     B, S = q.shape[:2]

@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.paged_attention import ops as pa_ops
 from repro.models.config import ModelConfig
 
 
@@ -282,16 +283,13 @@ def attention_block(p, x, cfg: ModelConfig, *, positions=None, cache=None,
                 bt = cache["block_table"]  # (B, P) page ids
                 psz = cache["k"].shape[2]
                 T = bt.shape[1] * psz
-                if (paged_kernel == "pallas" and shard is None
-                        and not cfg.mrope and not cfg.chunked_attention
+                if (paged_kernel == "pallas"
+                        and pa_ops.pallas_eligible(cfg, shard)
                         and positions.ndim == 2 and S <= T):
                     # eligible for the kernel: any S block (decode, chunked
                     # prefill, resume-recompute), default or per-row 1-D
-                    # positions.  Still XLA-only: M-RoPE (3-D positions),
-                    # chunked-local masking, mesh sharding (the kernel is a
-                    # single-device program), S > ring.
-                    from repro.kernels.paged_attention import ops as pa_ops
-
+                    # positions.  Still XLA-only: what `pallas_eligible`
+                    # refuses, and S > ring.
                     out, store_k, store_v = pa_ops.paged_attention_update(
                         q, k, v, cache["k"], cache["v"], bt, abs_pos[:, -1],
                         window=cfg.sliding_window,

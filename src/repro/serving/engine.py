@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention.ops import pallas_eligible
+from repro.kernels.paged_attention.paged_attention import live_pages
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.serving.kvcache import (DEFAULT_PAGE_SIZE, attn_cache_shape,
@@ -270,6 +272,13 @@ class PagedEngine:
         self._copy_dst = np.zeros((n_slots,), np.int32)
         self.decode_dispatches = 0
         self.prefill_dispatches = 0
+        # pages the Pallas decode dispatches streamed, and the pages their
+        # block tables held (n_slots * P a dispatch): 0 where attention
+        # takes the XLA path (models/layers.attention_block)
+        self._streams_pages = kernel == "pallas" and pallas_eligible(
+            cfg, self.plan)
+        self.kv_pages = 0
+        self.kv_pages_table = 0
 
     # --------------------------------------------------- slot lifecycle
 
@@ -346,6 +355,13 @@ class PagedEngine:
                 jnp.array(self._reset_mask), jnp.array(self._copy_src),
                 jnp.array(self._copy_dst), sampling)
         self.decode_dispatches += 1
+        if self._streams_pages:
+            # the kernel reads the pages up to each lane's newest position,
+            # idle lanes included
+            P = self.pages_per_slot
+            self.kv_pages += int(live_pages(self.slot_pos, self.page_size,
+                                            P, xp=np).sum())
+            self.kv_pages_table += self.n_slots * P
         self._reset_mask[:] = False
         self._copy_src[:] = 0
         self._copy_dst[:] = 0

@@ -617,29 +617,35 @@ class _BatcherBase:
     def step(self):
         """One engine tick.  With a telemetry sink attached, the tick is
         timed and annotated (seconds waiting on the device, active slots,
-        dispatches, CoW copies, page growths, preemptions) and the pool
-        gauge is refreshed; ``telemetry=None`` falls straight through to
-        the layout-specific `_step_inner` — zero per-tick overhead."""
+        dispatches, CoW copies, page growths, preemptions, and the pages
+        the Pallas decode dispatch streamed against its block table's)
+        and the pool gauge is refreshed; ``telemetry=None`` falls straight
+        through to the layout-specific `_step_inner` — zero per-tick
+        overhead."""
         tel = self.telemetry
         if tel is None:
             return self._step_inner()
+        eng = self.engine
         t0 = tel.now()
         w0 = tel.waited_s
-        d0 = self.engine.decode_dispatches + self.engine.prefill_dispatches
+        d0 = eng.decode_dispatches + eng.prefill_dispatches
         a0 = self.decode_active_slots
         c0 = getattr(self, "cow_copies", 0)
         g0 = getattr(self, "page_growths", 0)
         p0 = self.preemptions
+        k0 = getattr(eng, "kv_pages", 0)
+        kt0 = getattr(eng, "kv_pages_table", 0)
         out = self._step_inner()
         tel.tick(
             t0, tel.now() - t0,
             wait_s=tel.waited_s - w0,
             active=self.decode_active_slots - a0,
-            dispatches=self.engine.decode_dispatches
-            + self.engine.prefill_dispatches - d0,
+            dispatches=eng.decode_dispatches + eng.prefill_dispatches - d0,
             cow_copies=getattr(self, "cow_copies", 0) - c0,
             page_growths=getattr(self, "page_growths", 0) - g0,
-            preemptions=self.preemptions - p0)
+            preemptions=self.preemptions - p0,
+            kv_pages=getattr(eng, "kv_pages", 0) - k0,
+            kv_pages_table=getattr(eng, "kv_pages_table", 0) - kt0)
         alloc = getattr(self, "allocator", None)
         if alloc is not None:
             tel.gauge("pool_pages_in_use").set(alloc.in_use)

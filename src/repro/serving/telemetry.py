@@ -276,7 +276,8 @@ class Telemetry:
     def tick(self, t0: float, dur: float, **attrs):
         """Record one engine tick (start + wall seconds + annotations:
         seconds waiting on the device, active slots, dispatches, CoW
-        copies, pages grown)."""
+        copies, pages grown, preemptions, KV pages streamed by the Pallas
+        decode dispatch and its block table's pages)."""
         self.ticks.append((t0, dur, attrs))
 
     def span(self, name: str):

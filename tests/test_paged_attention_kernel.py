@@ -384,3 +384,107 @@ def test_oversized_block_fails_loud():
     last = jnp.array([S - 1], jnp.int32)
     with pytest.raises(ValueError, match="ring"):
         pa_ops.paged_attention(q, kp, kp, bt, last)
+
+
+# ---------------------------------------------- live pages, row-only writes
+
+
+@pytest.mark.parametrize("fuse", [False, True])
+@pytest.mark.parametrize("tile_k", [1, 2, 3])
+def test_dead_pages_never_read(tile_k, fuse):
+    """A 1-token slot, a mid-length slot and a wrapped full-ring slot,
+    with every page past each slot's live range full of NaN: the kernel
+    reads only ceil((last + 1) / page_size) pages of a slot (all P once
+    the ring wrapped), so its answer is the oracle's over finite pools."""
+    B, H, KV, hd, P = 3, 4, 2, 64, 4
+    q, kp, vp, kn, vn = _block_setup(jax.random.PRNGKey(21), B, 1, H, KV,
+                                     hd, 1 + B * P)
+    bt = jnp.arange(1, 1 + B * P, dtype=jnp.int32).reshape(B, P)
+    last = jnp.array([0, 37, 2 * P * PSZ + 9], jnp.int32)
+    dead = [int(bt[b, p]) for b in range(B) for p in range(P)
+            if p >= min(P, (int(last[b]) + PSZ) // PSZ)]
+    assert len(dead) == 3 + 1  # slot 0: pages 1-3; slot 1: page 3
+    nan_k = kp.at[jnp.array(dead)].set(jnp.nan)
+    nan_v = vp.at[jnp.array(dead)].set(jnp.nan)
+    if fuse:
+        out, okp, _ = pa_ops.paged_attention_update(
+            q, kn, vn, nan_k, nan_v, bt, last, tile_k=tile_k)
+        want, wkp, _ = pa_ref.reference_paged_update(q, kn, vn, kp, vp, bt,
+                                                     last)
+        live = np.setdiff1d(np.arange(1 + B * P), dead)
+        np.testing.assert_array_equal(np.asarray(okp)[live],
+                                      np.asarray(wkp)[live])
+    else:
+        out = pa_ops.paged_attention(q, nan_k, nan_v, bt, last,
+                                     tile_k=tile_k)
+        want = pa_ref.reference_paged_attention_block(q, kp, vp, bt, last)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-6, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("tile_k", [2, 3])
+def test_fused_write_touches_only_new_rows(tile_k, dtype):
+    """An S=16 block whose rows straddle a page boundary, in a fresh ring
+    and in a wrapped one, with tile_k > 1: the returned pools equal the
+    inputs bit for bit everywhere but the new rows, which hold the new
+    K/V in the pool's dtype (f32 pools take row copies, bf16 pools whole
+    page merges)."""
+    B, S, H, KV, hd, P = 2, 16, 4, 2, 64, 4
+    T = P * PSZ
+    q, kp, vp, kn, vn = _block_setup(jax.random.PRNGKey(22), B, S, H, KV,
+                                     hd, 1 + B * P)
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    bt = jnp.array([[3, 7, 1, 5], [2, 8, 6, 4]], jnp.int32)
+    last = jnp.array([PSZ + 7, T + 40], jnp.int32)  # rows 8-23, 25-40
+    out, okp, ovp = pa_ops.paged_attention_update(q, kn, vn, kp, vp, bt,
+                                                  last, tile_k=tile_k)
+    new = np.zeros(kp.shape[:3], bool)          # (n_pages, KV, psz)
+    want_k, want_v = np.asarray(kp).copy(), np.asarray(vp).copy()
+    for b in range(B):
+        for s in range(S):
+            ring = (int(last[b]) - S + 1 + s) % T
+            page, row = int(bt[b, ring // PSZ]), ring % PSZ
+            new[page, :, row] = True
+            want_k[page, :, row] = np.asarray(kn[b, s].astype(dtype))
+            want_v[page, :, row] = np.asarray(vn[b, s].astype(dtype))
+    assert new.sum() == B * S * KV
+    for got, was, want in ((okp, kp, want_k), (ovp, vp, want_v)):
+        got = np.asarray(got)
+        np.testing.assert_array_equal(got[~new], np.asarray(was)[~new])
+        np.testing.assert_array_equal(got, want)
+    ref_out, _, _ = pa_ref.reference_paged_update(q, kn, vn, kp, vp, bt,
+                                                  last)
+    tol = 2e-6 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref_out, np.float32),
+                               rtol=tol, atol=tol * 5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_idle_lanes_on_null_page_beside_live_lanes(dtype):
+    """Idle lanes parked on the null page 0 (their positions pinned where
+    their last request left them) between live lanes: the live lanes'
+    answers and pages match the oracle, the idle lanes' writes land on
+    page 0 only, and every output is finite."""
+    B, H, KV, hd, P = 4, 4, 2, 64, 3
+    q, kp, vp, kn, vn = _block_setup(jax.random.PRNGKey(23), B, 1, H, KV,
+                                     hd, 8)
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    bt = jnp.array([[1, 2, 3], [0, 0, 0], [4, 5, 0], [0, 0, 0]], jnp.int32)
+    last = jnp.array([40, 37, 20, 0], jnp.int32)
+    out, okp, ovp = pa_ops.paged_attention_update(q, kn, vn, kp, vp, bt,
+                                                  last)
+    live = np.array([0, 2])
+    want, wkp, wvp = pa_ref.reference_paged_update(
+        q[live], kn[live], vn[live], kp, vp, bt[live], last[live])
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    tol = 2e-6 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol * 5)
+    for got, ref in ((okp, wkp), (ovp, wvp)):  # page 0 is don't-care
+        np.testing.assert_array_equal(np.asarray(got)[1:],
+                                      np.asarray(ref)[1:])

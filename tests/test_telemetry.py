@@ -354,3 +354,39 @@ def test_tick_records_its_wait(setup):
             assert attrs["wait_s"] > 0.0
     assert sum(a["wait_s"] for _, _, a in tel.ticks) == \
         pytest.approx(tel.waited_s)
+
+
+@pytest.mark.parametrize("kernel", ["pallas", "xla"])
+def test_tick_counts_streamed_kv_pages(setup, kernel):
+    """Each tick books the pages its Pallas decode dispatch streams,
+    sum over the lanes of min(P, ceil((pos + 1) / page_size)) at the
+    dispatch, against its block table's n_slots * P; both are 0 on the
+    XLA path and on a tick with no decode dispatch."""
+    cfg, params = setup
+    tel = Telemetry()
+    eng = ContinuousBatcher(cfg, params, ServingConfig(
+        n_slots=2, capacity=64, cache_layout="paged", kernel=kernel,
+        allocation="lazy", telemetry=tel))
+    pe = eng.engine
+    ps, P = pe.page_size, pe.pages_per_slot
+    seen = []
+    decode = pe.decode
+
+    def spy(*a, **k):
+        seen.append(sum(min(P, -(-(int(p) + 1) // ps))
+                        for p in pe.slot_pos))
+        return decode(*a, **k)
+
+    pe.decode = spy
+    eng.submit(_reqs(cfg, n=3, plen=20, max_new=6))
+    eng.run()
+    assert seen and len(seen) == eng.decode_ticks
+    ticks = [a for _, _, a in tel.ticks]
+    if kernel == "xla":
+        assert all(a["kv_pages"] == a["kv_pages_table"] == 0 for a in ticks)
+        return
+    decoded = [a for a in ticks if a["kv_pages_table"]]
+    assert [a["kv_pages"] for a in decoded] == seen
+    assert all(a["kv_pages_table"] == 2 * P for a in decoded)
+    assert sum(a["kv_pages"] for a in ticks) < sum(
+        a["kv_pages_table"] for a in ticks)
